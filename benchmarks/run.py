@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro.runtime.compile_cache import use_compile_cache
+
 MODULES = [
     ("table1_mnli", "benchmarks.table1_mnli"),
     ("table2_mrpc", "benchmarks.table2_mrpc"),
@@ -33,6 +35,7 @@ def main() -> None:
         "of the CSV rows; written even when a benchmark fails",
     )
     args = ap.parse_args()
+    use_compile_cache()
     sel = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

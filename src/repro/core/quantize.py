@@ -53,12 +53,6 @@ from repro.configs.base import BASE_DTYPES  # noqa: F401  (re-exported)
 
 Pytree = Any
 
-#: fp8-e4m3 availability is a jax-version property, not a backend one —
-#: EngineConfig validation consults this to reject ``base_dtype="fp8"``
-#: before any device memory is touched.
-FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
-FP8_SUPPORTED = FP8_DTYPE is not None
-
 #: Largest finite magnitude representable per dtype (the symmetric range
 #: the per-channel amax maps onto).  int8 uses 127 (not 128): symmetric,
 #: so q = -q is always representable and dequant needs no zero-point.
@@ -98,8 +92,6 @@ def quantize_weight(W: jax.Array, base_dtype: str) -> Dict[str, jax.Array]:
             f"base_dtype={base_dtype!r} is not quantized; expected one of "
             f"{tuple(_QMAX)}"
         )
-    if base_dtype == "fp8" and not FP8_SUPPORTED:
-        raise ValueError("fp8 base_dtype needs jax.numpy.float8_e4m3fn")
     qmax = _QMAX[base_dtype]
     W32 = W.astype(jnp.float32)
     amax = jnp.max(jnp.abs(W32), axis=-2)  # (…, N)
@@ -108,7 +100,7 @@ def quantize_weight(W: jax.Array, base_dtype: str) -> Dict[str, jax.Array]:
     if base_dtype == "int8":
         q = jnp.clip(jnp.round(scaled), -qmax, qmax).astype(jnp.int8)
     else:
-        q = scaled.astype(FP8_DTYPE)
+        q = scaled.astype(jnp.float8_e4m3fn)
     return {"q": q, "scale": scale}
 
 

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
 
 _NEG = -1e30
 
@@ -97,7 +96,7 @@ def decode_attention_kernel(
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -42,15 +42,27 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths), s
 
 
+def _lane_block(dim: int, cap: int) -> int:
+    """Block size for a lane-mapped (last-two) block dimension.  Mosaic
+    takes a block dim that is a multiple of 128 or the whole array dim:
+    the largest 128-multiple divisor of ``dim`` up to ``cap`` keeps VMEM
+    bounded at wide layers, and the whole dim is the fallback (K = 576,
+    N = 192 have no such divisor)."""
+    for b in range(cap - cap % 128, 0, -128):
+        if dim % b == 0:
+            return b
+    return dim
+
+
 def _matmul_blocking(x2, N, K):
-    """Shared tiling for the qrlora matmul kernels: pad rows to the bm
-    block, gcd-fit bn/bk.  Returns (padded x2, original M, bm, bn, bk)."""
+    """Shared tiling for the qrlora matmul kernels: bm is the whole M up to
+    256 rows, else 256 with the rows padded to it; bn/bk per
+    :func:`_lane_block` (never padded).  Returns (padded x2, original M,
+    bm, bn, bk)."""
     M = x2.shape[0]
     bm = 256 if M % 256 == 0 or M > 256 else M
     x2, M0 = _pad_to(x2, bm, 0)
-    bn = int(np.gcd(N, 256))
-    bk = int(np.gcd(K, 512))
-    return x2, M0, bm, bn, bk
+    return x2, M0, bm, _lane_block(N, 256), _lane_block(K, 512)
 
 
 # ---------------------------------------------------------------------------

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
 
 _NEG = -1e30
 _LOOKAHEAD = 2  # DMA double-buffering depth (outstanding copies per pool)
@@ -131,8 +130,8 @@ def paged_decode_attention_kernel(
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, dh), lambda b, tbl, lens: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=pl.BlockSpec((1, H, dh), lambda b, tbl, lens: (b, 0, 0)),
         scratch_shapes=[
@@ -150,6 +149,6 @@ def paged_decode_attention_kernel(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dh), q.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_tbl.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)

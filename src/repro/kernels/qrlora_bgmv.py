@@ -49,8 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
-
 
 def lam_gather_sharded(
     lam_table: jax.Array,  # (n_slots, r), sharded over axis 0 along `axis`
@@ -72,8 +70,6 @@ def lam_gather_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     def body(tab, seg_ids):
         n_local = tab.shape[0]
         local = seg_ids - jax.lax.axis_index(axis) * n_local
@@ -82,8 +78,9 @@ def lam_gather_sharded(
         rows = jnp.where(ok[:, None], rows, jnp.zeros_like(rows))
         return jax.lax.psum(rows, axis)
 
-    return shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P()
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
+        check_vma=False,
     )(lam_table, seg.astype(jnp.int32))
 
 
@@ -108,8 +105,6 @@ def ba_gather_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     def body(b, a):
         return (
             jax.lax.all_gather(b, axis, axis=b.ndim - 1, tiled=True),
@@ -118,11 +113,12 @@ def ba_gather_sharded(
 
     b_spec = P(*([None] * (B.ndim - 1)), axis)
     a_spec = P(*([None] * (A.ndim - 2)), axis, None)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(b_spec, a_spec),
         out_specs=(P(), P()),
+        check_vma=False,
     )(B, A)
 
 
@@ -211,10 +207,11 @@ def qrlora_bgmv_kernel(
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="qrlora_bgmv",
     )(x, W, B, A, lam_table, seg)
 
 
@@ -312,10 +309,11 @@ def qrlora_bgmv_quant_kernel(
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="qrlora_bgmv_quant",
     )(x, q, w_scale, B, A, lam_table, seg)
 
 
@@ -415,10 +413,11 @@ def qrlora_bgmv_rows_kernel(
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="qrlora_bgmv_rows",
     )(x, W, w_scale, B, A, lam_rows)
 
 
@@ -453,8 +452,6 @@ def qrlora_bgmv_fused_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     ws = (
         w_scale
         if w_scale is not None
@@ -473,9 +470,10 @@ def qrlora_bgmv_fused_sharded(
             scale=scale, bm=bm, bn=bn, bk=bk, interpret=interpret,
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(axis), P()),
         out_specs=P(),
+        check_vma=False,
     )(x, W, ws, B, A, lam_table, seg.astype(jnp.int32))

@@ -7,8 +7,13 @@ XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh as make_mesh  # version shim lives in compat
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code places
+    arrays through sharding constraints and leaves the rest to GSPMD."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

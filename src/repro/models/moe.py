@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.sharding.rules import get_mesh, _rules, shard_map
+from repro.sharding.rules import get_mesh, _rules
 
 
 def init_moe_params(key, cfg: ModelConfig, n: int, dtype) -> Dict:
@@ -165,10 +165,11 @@ def moe_ffn(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Arr
     psum_axis = model_ax if f_ok else None
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), we_spec, we_spec, wd_spec),
         out_specs=(x_spec, P()),
+        check_vma=False,
     )
     def run(xl, wr, wg, wu, wd):
         if d_ok:

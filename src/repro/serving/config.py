@@ -42,7 +42,6 @@ import dataclasses
 from typing import Optional
 
 from repro.configs.base import BASE_DTYPES
-from repro.core.quantize import FP8_SUPPORTED
 from repro.models.transformer import PAGED_FAMILIES
 
 LAYOUTS = ("auto", "paged", "oracle_dense")
@@ -109,8 +108,6 @@ class EngineConfig:
     #: alone; "int8"/"fp8" quantize every adapted base projection
     #: per-output-channel at engine construction (``core/quantize.py``) and
     #: dequantize in the kernel epilogue — λ, B, A stay full precision.
-    #: "fp8" needs jax.numpy.float8_e4m3fn (validated here, before any
-    #: device memory is touched).
     base_dtype: str = "bf16"
     #: Shard the shared QR factors B/A over their rank dim along the mesh
     #: model axis (the ``qr_rank`` logical axis) — divides their at-rest
@@ -177,11 +174,6 @@ class EngineConfig:
         if self.base_dtype not in BASE_DTYPES:
             raise ValueError(
                 f"base_dtype={self.base_dtype!r} must be one of {BASE_DTYPES}"
-            )
-        if self.base_dtype == "fp8" and not FP8_SUPPORTED:
-            raise ValueError(
-                "base_dtype='fp8' needs jax.numpy.float8_e4m3fn, which this "
-                "jax build does not provide — use base_dtype='int8'"
             )
         if self.layout == "oracle_dense":
             if self.share_prefix:

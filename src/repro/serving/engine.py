@@ -105,6 +105,7 @@ drain, ``stream()`` yields per-token :class:`TokenEvent`\\ s as they decode.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from contextlib import nullcontext
 from typing import Any, Dict, Iterator, List, Optional
@@ -113,10 +114,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh
 from repro.configs.base import ModelConfig
 from repro.core import adapter_api
 from repro.core.quantize import quantize_base_params
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.obs import Telemetry
 from repro.models.lane_state import extract_lane, restore_lane
@@ -189,8 +190,13 @@ class MultiTenantEngine:
         config: Optional[EngineConfig] = None,
         *,
         params: Optional[Pytree] = None,
+        device: Optional[jax.Device] = None,
         **legacy,
     ):
+        """``device`` commits the engine's params, λ tables and KV cache to
+        that one device (a one-chip replica, ``serving/replica.py``); its
+        steps then run there.  ``None`` leaves placement to JAX's default
+        device, or to the mesh under ``shard_lam``/``shard_ba``."""
         if legacy:
             if config is not None:
                 raise TypeError(
@@ -249,6 +255,14 @@ class MultiTenantEngine:
             config.base_dtype if config.base_dtype != "bf16" else cfg.base_dtype
         )
         self.params = quantize_base_params(self.params, self.base_dtype)
+        if device is not None and (config.shard_lam or config.shard_ba):
+            raise ValueError(
+                "device= pins a one-device engine; shard_lam/shard_ba span a mesh"
+            )
+        self.device = device
+        if device is not None:
+            # a copy per device: replicas never share one tree across chips
+            self.params = jax.device_put(self.params, device)
         # λ-store tiers + sharding: a 1-D "model" mesh over the local
         # devices carries the slot axis of the packed λ tables when
         # shard_lam is on; the minimal rule table maps ONLY the λ-table
@@ -285,7 +299,7 @@ class MultiTenantEngine:
         with self._rules_ctx():
             self.lam_store = LamStore.from_params(
                 self.params, n_slots=n_slots, cold_slots=cold_slots,
-                cold_path=config.cold_path, mesh=self._mesh,
+                cold_path=config.cold_path, mesh=self._mesh, device=device,
             )
         # tier pressure can drop a tenant without an explicit evict — its
         # prefix-cache family must be reclaimed just as eagerly
@@ -361,6 +375,8 @@ class MultiTenantEngine:
             self.cache = self.model.init_decode_state(
                 n_lanes, max_len, self.dtype, per_lane=True
             )
+        if device is not None:
+            self.cache = jax.device_put(self.cache, device)
         self.steps = 0
         self.decoded_tokens = 0
         self.prefill_buckets: set = set()  # padded lengths actually compiled
@@ -558,7 +574,8 @@ class MultiTenantEngine:
         # logical-axis rules for the λ-table sharding — keep the rule
         # context active around every call (the tracing one included)
         self._prefill = self._with_rules(jax.jit(_prefill))
-        self._decode = self._with_rules(jax.jit(_decode, static_argnums=(4,)))
+        self._decode_jit = jax.jit(_decode, static_argnums=(4,))
+        self._decode = self._with_rules(self._decode_jit)
         self._restore = jax.jit(_restore)
         self._extract = jax.jit(_extract)
         self._reset = jax.jit(_reset)
@@ -1658,6 +1675,16 @@ class MultiTenantEngine:
             self.step()
             yield from self.events
 
+    def lower_decode(self):
+        """Lower the plain decode step for the current lanes at the widest
+        attend bucket — ``.compile().as_text()`` shows what the device runs
+        (e.g. whether the adapter matmuls are the BGMV kernel)."""
+        tok = jnp.zeros((self.n_lanes, 1), jnp.int32)
+        seg = jnp.asarray(self.scheduler.batch_composition())
+        ab = self.max_blocks if self.paged else None
+        with self._rules_ctx():
+            return self._decode_jit.lower(self._params_view(), self.cache, tok, seg, ab)
+
     # -- accounting ---------------------------------------------------------
 
     def kv_cache_bytes(self) -> int:
@@ -1724,21 +1751,30 @@ def merge_tenant_params(params: Pytree, cfg: ModelConfig, lam_tree) -> Pytree:
     return {**params, "groups": groups}
 
 
+@functools.lru_cache(maxsize=None)
+def _reference_steps(cfg: ModelConfig):
+    """(model, jitted prefill, jitted decode step) of the merged-weight
+    reference — one compilation per config, not one per call (an eager
+    ``lax.scan`` recompiles its layer stack every time it is called)."""
+    model = build_model(cfg)
+    prefill = jax.jit(lambda p, c, t: model.prefill(p, c, tokens=t))
+    decode = jax.jit(lambda p, c, t: model.decode_step(p, c, token=t))
+    return model, prefill, decode
+
+
 def reference_decode(
     cfg: ModelConfig, params: Pytree, lam_tree, prompt, n_tokens: int, max_len: int
 ):
     """Greedy decode of one prompt through merged weights (no adapters on
     the runtime path); returns (tokens list, logits (n_tokens, V))."""
-    model = build_model(cfg)
+    model, prefill, decode = _reference_steps(cfg)
     merged = merge_tenant_params(params, cfg, lam_tree)
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     cache = model.init_decode_state(1, max_len, dtype)
-    logits, cache = model.prefill(merged, cache, tokens=jnp.asarray(prompt)[None, :])
+    logits, cache = prefill(merged, cache, jnp.asarray(prompt)[None, :])
     toks, rows = [int(jnp.argmax(logits[0]))], [np.asarray(logits[0])]
     for _ in range(n_tokens - 1):
-        logits, cache = model.decode_step(
-            merged, cache, token=jnp.asarray([[toks[-1]]], jnp.int32)
-        )
+        logits, cache = decode(merged, cache, jnp.asarray([[toks[-1]]], jnp.int32))
         toks.append(int(jnp.argmax(logits[0])))
         rows.append(np.asarray(logits[0]))
     return toks, np.stack(rows)

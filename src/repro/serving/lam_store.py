@@ -295,10 +295,12 @@ class LamStore:
         cold_slots: int = 0,
         cold_path: Optional[str] = None,
         mesh=None,
+        device=None,
     ):
         assert n_slots >= 2, "need slot 0 (base) plus at least one tenant slot"
         self._lam_shapes = dict(lam_shapes)
         self.mesh = mesh
+        self.device = device  # one-device engines commit their tables here
         self.shard_axis: Optional[str] = None
         if mesh is not None:
             from repro.sharding.rules import logical_spec
@@ -378,6 +380,8 @@ class LamStore:
 
             spec = logical_spec(*([None] * (len(row_shape) - 1)), "lam_slots", None)
             tab = jax.device_put(tab, NamedSharding(self.mesh, spec))
+        elif self.device is not None:
+            tab = jax.device_put(tab, self.device)
         return tab
 
     # -- construction -------------------------------------------------------

@@ -2,10 +2,12 @@
 
 One :class:`EngineReplica` wraps one :class:`~repro.serving.engine.
 MultiTenantEngine` with a replica identity (id, role, device group) and the
-load signal the router's spillover policy reads.  N replicas share ONE
-frozen parameter tree — QR-LoRA's whole premise is that per-tenant state is
-~601 λ scalars over shared factors, so replicating an engine costs KV blocks
-and λ tables, not another copy of the base weights.
+load signal the router's spillover policy reads.  On a host with a device
+per replica, each replica's engine lives on its own device: the frozen tree
+is initialized once and copied to every device, and the replica's KV pool
+and λ tables are committed there too.  With fewer devices than replicas
+(single-device CPU tests only) the replicas share one tree by reference and
+time-share the device.
 
 Transport seam
 ==============
@@ -103,9 +105,8 @@ class EngineReplica:
         self.replica_id = replica_id
         self.engine = engine
         self.role = role
-        #: device group this replica would pin on a multi-device host
-        #: (informational at single-device smoke scale — see
-        #: ``sharding.replica_device_groups``)
+        #: device group of this replica (``sharding.replica_device_groups``);
+        #: its engine lives on the first one when groups are disjoint
         self.devices = list(devices) if devices is not None else []
         self.alive = True
 
@@ -138,10 +139,12 @@ def build_replicas(
     lams: Optional[Dict[str, Any]] = None,
     config_overrides: Optional[Callable[[int, EngineConfig], EngineConfig]] = None,
 ) -> List[EngineReplica]:
-    """Build ``n`` replicas sharing one frozen parameter tree.
+    """Build ``n`` replicas of one frozen parameter tree.
 
     Replica 0 initializes (or adopts ``params``); the rest are constructed
-    with ``params=`` pointing at the same tree — no re-init, no copy.  With
+    from the same tree — no re-init.  With a device per replica, each
+    engine is committed to the first device of its group and holds its own
+    copy of the tree; otherwise (single-device CPU) they share it.  With
     ``roles=None`` every replica is ``"both"``; pass explicit roles for a
     disaggregated layout (the router validates the mix).  ``lams``
     pre-registers a tenant catalog on every replica via the batch API —
@@ -155,10 +158,13 @@ def build_replicas(
     if roles is not None and len(roles) != n:
         raise ValueError(f"got {len(roles)} roles for {n} replicas")
     groups = replica_device_groups(n)
+    own_device = len({g[0] for g in groups}) == n
     replicas: List[EngineReplica] = []
     for i in range(n):
         rcfg = config if config_overrides is None else config_overrides(i, config)
-        eng = MultiTenantEngine(cfg, rcfg, params=params)
+        eng = MultiTenantEngine(
+            cfg, rcfg, params=params, device=groups[i][0] if own_device else None
+        )
         if params is None:
             params = eng.params  # replica 0 initialized; siblings share
         replicas.append(EngineReplica(

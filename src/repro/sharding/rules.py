@@ -35,8 +35,6 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as shard_map  # version shim lives in compat
-
 _state = threading.local()
 
 
@@ -264,17 +262,22 @@ def replica_device_groups(n: int) -> "list":
 
     Replicas are data-parallel copies of the whole engine, so they split
     the device pool along what would be the mesh *data* axis: with ``d``
-    devices, replica ``i`` owns devices ``i*d//n : (i+1)*d//n``.  With
-    fewer devices than replicas (the single-host CPU smoke case) every
-    group falls back to the full device list — replicas then time-share
-    devices, and the scaling win comes from cache locality rather than
-    parallel compute.  Cross-host layouts later swap this for a
-    process-spanning partition without touching the replica tier.
+    devices, replica ``i`` owns devices ``i*d//n : (i+1)*d//n``.  Fewer
+    devices than replicas is a CPU-only case (single-device tests): every
+    group is then the full device list, the replicas time-share it, and
+    the scaling win comes from cache locality rather than parallel
+    compute.  On an accelerator it is an error — replicas stacked on one
+    chip would hide that the host lacks the chips.
     """
     if n < 1:
         raise ValueError(f"n={n} must be >= 1")
     devices = jax.devices()
     if len(devices) < n:
+        if devices[0].platform != "cpu":
+            raise ValueError(
+                f"{n} replicas need {n} {devices[0].platform} devices; "
+                f"this host has {len(devices)}"
+            )
         return [list(devices) for _ in range(n)]
     return [
         list(devices[i * len(devices) // n: (i + 1) * len(devices) // n])

@@ -83,7 +83,7 @@ _SCRIPT = textwrap.dedent(
 def test_distributed_8dev():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # 8 virtual CPU devices; never the host's TPU
     r = subprocess.run(
         [sys.executable, "-c", _SCRIPT],
         capture_output=True,

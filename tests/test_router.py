@@ -241,3 +241,83 @@ def test_disaggregated_handoff_bit_identical_zero_recompute():
     handoffs = sum(
         s["value"] for s in snap if s["labels"]["outcome"] == "handoff")
     assert handoffs == len(routed)
+
+
+# ---------------------------------------------------------------------------
+# device placement: one replica per device, never stacked on an accelerator
+# ---------------------------------------------------------------------------
+
+
+def test_engine_device_commits_params_cache_and_lam_tables():
+    cfg = get_reduced("smollm-135m")
+    dev = jax.devices()[0]
+    eng = MultiTenantEngine(cfg, _paged(), device=dev)
+    leaves = jax.tree_util.tree_leaves(
+        (eng.params, eng.cache, eng.lam_store.tables)
+    )
+    assert all(leaf.committed and leaf.devices() == {dev} for leaf in leaves)
+    with pytest.raises(ValueError, match="mesh"):
+        MultiTenantEngine(cfg, EngineConfig(shard_lam=True), device=dev)
+
+
+class _FakeTpu:
+    platform = "tpu"
+
+
+def test_replica_groups_refuse_stacking_on_an_accelerator(monkeypatch):
+    """Fewer chips than replicas would stack replicas on one chip and hide
+    that the host lacks the chips; only the CPU time-shares."""
+    from repro.sharding import replica_device_groups
+
+    chips = [_FakeTpu()]
+    monkeypatch.setattr(jax, "devices", lambda: chips)
+    with pytest.raises(ValueError, match="4 replicas need 4 tpu devices"):
+        replica_device_groups(4)
+    assert replica_device_groups(1) == [chips]
+
+
+_FOUR_DEVICE_SCRIPT = """
+import jax, numpy as np
+from repro.configs import get_reduced
+from repro.serving import EngineConfig, MultiTenantEngine, Router, build_replicas
+from repro.launch.serve_multi import tenant_lambdas, tenant_prompts
+devs = jax.devices()
+assert len(devs) == 4, devs
+cfg = get_reduced("smollm-135m")
+econf = EngineConfig(layout="paged", n_lanes=2, n_slots=9, max_len=32, block_size=8)
+one = MultiTenantEngine(cfg, econf, device=devs[0])
+lams = tenant_lambdas(one.params, 8, 0, 0.3)
+one.add_tenants({t: l for t, l in lams.items() if t != "__base__"})
+prompts = tenant_prompts(lams, cfg.vocab_size, 9, 0)
+want = {r.tenant: r.tokens for r in
+        [one.submit(t, p, 6) for t, p in prompts.items()]}
+one.run()
+want = {t: list(v) for t, v in want.items()}
+reps = build_replicas(cfg, econf, 4, params=one.params)
+for i, rep in enumerate(reps):
+    e = rep.engine
+    leaves = jax.tree_util.tree_leaves((e.params, e.cache, e.lam_store.tables))
+    assert {d for l in leaves for d in l.devices()} == {devs[i]}, i
+router = Router(reps, telemetry=False)
+router.add_tenants(lams)
+routed = {t: router.submit(t, p, 6) for t, p in prompts.items()}
+router.run()
+assert {t: r.tokens for t, r in routed.items()} == want
+assert sum(r.engine.decoded_tokens > 0 for r in reps) >= 2
+print("FOUR_REPLICAS_OK")
+"""
+
+
+def test_four_replicas_each_on_own_device():
+    """Four virtual CPU devices: replica i's params, KV pool and λ tables
+    sit on device i only, and routed tokens equal one engine's."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SCRIPT], cwd=root,
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert "FOUR_REPLICAS_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-3000:]

@@ -490,3 +490,48 @@ def test_engine_quantum_preempts_at_most_one_lane_per_waiter():
     assert eng.slice_preemptions == 1, "both lanes churned for one waiter"
     assert before_active == 2
     eng.run()
+
+
+# ---------------------------------------------------------------------------
+# serve_multi's verification rule against the merged-weight reference
+# ---------------------------------------------------------------------------
+
+
+def _ref_rows(margins):
+    """Reference logits whose top-2 margin at position i is margins[i]
+    (token 1 leads token 0)."""
+    rows = np.zeros((len(margins), 4), np.float32)
+    rows[:, 1] = 1.0
+    rows[:, 0] = 1.0 - np.asarray(margins, np.float32)
+    return rows
+
+
+@pytest.mark.parametrize("case,ok", [
+    ("identical", True),
+    ("logit_gap_over_tol", False),
+    ("split_at_near_tie", True),
+    ("split_at_margin_over_tol", False),
+    ("short_generation", False),
+])
+def test_compare_to_reference_rule(case, ok):
+    """Logits within tol through the first split; a split only where the
+    reference's top-2 margin is under tol; a short output never passes."""
+    from repro.launch.serve_multi import compare_to_reference
+
+    tol = 0.25
+    ref = _ref_rows([1.0, 0.3, 0.2, 1.0])
+    ref_toks = [1, 1, 1, 1]
+    logits, toks = ref.copy(), list(ref_toks)
+    if case == "logit_gap_over_tol":
+        logits[3, 2] += 0.3
+    elif case == "split_at_near_tie":
+        toks[2:] = [0, 3]  # margin 0.2 < tol; position 3 is not compared
+        logits[3] = 9.0
+    elif case == "split_at_margin_over_tol":
+        toks[1] = 0  # margin 0.3: within 2·tol, but not a near-tie
+    elif case == "short_generation":
+        toks, logits = toks[:3], logits[:3]
+    got, err, n, margin = compare_to_reference(toks, logits, ref_toks, ref, tol)
+    assert got is ok
+    if case == "split_at_near_tie":
+        assert n == 3 and margin == pytest.approx(0.2) and err == 0.0
