@@ -1,0 +1,2 @@
+"""device_idle.tails: see ``bench.readers.device_idle``."""
+from bench.readers import device_idle as read  # noqa: F401

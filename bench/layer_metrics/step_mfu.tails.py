@@ -1,0 +1,2 @@
+"""step_mfu.tails: see ``bench.readers.step_mfu``."""
+from bench.readers import step_mfu as read  # noqa: F401
