@@ -8,10 +8,11 @@ inside a single ``step()``.  This package makes that loop legible:
   gauges, and fixed-bucket histograms.  Pure Python, lock-free (the engine
   loop is single-threaded), no-op stubs when disabled so the decode hot
   path pays ~zero.
-* :mod:`repro.obs.tracing` — per-request :class:`RequestTrace` milestone
-  logs and a Chrome/Perfetto ``trace_event`` :class:`Tracer`: an engine run
-  exports as a lane timeline (prefill/decode/preemption spans per lane,
-  queue-wait spans per request).
+* :mod:`repro.obs.tracing` — ``serve.*`` host spans on the JAX profiler's
+  clock (:func:`~repro.obs.tracing.span`), per-request
+  :class:`RequestTrace` milestone logs and a Chrome/Perfetto
+  ``trace_event`` :class:`Tracer`: an engine run exports as a lane timeline
+  (prefill/decode/preemption spans per lane, queue-wait spans per request).
 * :mod:`repro.obs.telemetry` — the :class:`Telemetry` facade the engine
   carries: the serving metric catalog (TTFT/TBT/E2E, step phases,
   preemption/deferral causes, cache hit rates, tier occupancy) plus the

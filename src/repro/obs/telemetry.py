@@ -26,15 +26,18 @@ engine event              recorded as
                           request span, instant marker
 ``on_retire``             ``serve_e2e_ms``, ``serve_retired_total``, closes the
                           request span
-``phase``                 ``serve_step_phase_ms{phase}`` — where ``step()``
-                          spends host time (admit/prefill_chunk/grow/
-                          dispatch/sync/emit)
+``span``                  a ``serve.<phase>`` profiler span around one
+                          ``step()`` phase (quantum/admit/prefill_chunk/grow/
+                          dispatch/sync/emit/draft/verify) and its duration
+                          in ``serve_step_phase_ms{phase}``
 ========================  ====================================================
 
 All times come from one ``perf_counter`` epoch shared with the tracer, so
 histogram latencies and trace spans line up.  With ``enabled=False`` every
 method returns immediately and every handle is the shared no-op instrument
-— the disabled engine pays one predicate per event.
+— the disabled engine pays one predicate per event.  Phase spans open on
+the profiler's clock either way: a ``jax.profiler`` session decides whether
+they are recorded.
 """
 from __future__ import annotations
 
@@ -42,7 +45,7 @@ import time
 from typing import Any, Dict, Optional
 
 from repro.obs.metrics import DEFAULT_MS_BUCKETS, MetricsRegistry
-from repro.obs.tracing import PID_ENGINE, PID_QUEUE, RequestTrace, Tracer
+from repro.obs.tracing import PID_ENGINE, PID_QUEUE, RequestTrace, Tracer, span
 
 #: Engine-track thread id for whole-step spans (draft/verify) that belong to
 #: no single lane — rendered above the lane rows in the trace viewer.
@@ -271,17 +274,18 @@ class Telemetry:
         if drafted:
             self.spec_acceptance.observe(accepted / drafted)
 
-    def on_spec_phase(self, name: str, t0: float, t1: float) -> None:
-        """A whole-step draft/verify span: a ``step_phase`` observation plus
-        a step-track trace span (no single lane owns it)."""
-        self.phase(name, t1 - t0)
-        if self.tracer:
-            self.tracer.complete(name, PID_ENGINE, TID_STEP, t0, t1 - t0)
+    def on_spec_phase(self, name: str) -> "PhaseSpan":
+        """A whole-step draft/verify phase: :meth:`span` plus a step-track
+        trace span (no single lane owns it)."""
+        return PhaseSpan(self, name, True, {})
 
     # -- step phases --------------------------------------------------------
 
-    def phase(self, name: str, seconds: float) -> None:
-        self.step_phase.labels(phase=name).observe(seconds * 1e3)
+    def span(self, name: str, **args) -> "PhaseSpan":
+        """One ``step()`` phase: the profiler span ``serve.<name>`` (with
+        ``args`` as its stats) and, with telemetry on, its duration in
+        ``serve_step_phase_ms{phase=name}``."""
+        return PhaseSpan(self, name, False, args)
 
     # -- export -------------------------------------------------------------
 
@@ -292,3 +296,34 @@ class Telemetry:
         if self.tracer is None:
             raise RuntimeError("tracing is disabled on this engine")
         self.tracer.write(path)
+
+
+class PhaseSpan:
+    """Context manager behind :meth:`Telemetry.span`; ``t0``/``t1`` are its
+    bounds on the telemetry clock, ``set_metadata`` adds span stats known
+    only inside it."""
+
+    __slots__ = ("_tel", "_name", "_step_track", "_ann", "t0", "t1")
+
+    def __init__(self, tel: Telemetry, name: str, step_track: bool,
+                 args: Dict[str, Any]):
+        self._tel, self._name, self._step_track = tel, name, step_track
+        self._ann = span(name, **args)
+
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "PhaseSpan":
+        self._ann.__enter__()
+        self.t0 = self._tel.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = self._tel.now()
+        self._ann.__exit__(*exc)
+        tel = self._tel
+        if tel.enabled:
+            dt = self.t1 - self.t0
+            tel.step_phase.labels(phase=self._name).observe(dt * 1e3)
+            if self._step_track and tel.tracer:
+                tel.tracer.complete(self._name, PID_ENGINE, TID_STEP, self.t0, dt)

@@ -1,6 +1,13 @@
-"""Request-span tracing: Chrome/Perfetto ``trace_event`` export.
+"""Request-span tracing: profiler spans and Chrome/Perfetto ``trace_event``
+export.
 
-Two cooperating pieces:
+Three pieces:
+
+* :func:`span` — a host span ``serve.<name>`` on the JAX profiler's clock,
+  so a ``jax.profiler`` capture places the engine's and the router's host
+  work next to the device programs it dispatches.  Spans always open: the
+  profiler session is the switch, and with none running a span costs about
+  half a microsecond.
 
 * :class:`RequestTrace` — per-request milestone log, attached to
   ``Request.trace`` at submission.  Every lifecycle transition the engine
@@ -32,8 +39,17 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 PID_ENGINE = 0  # lane timeline: tid = lane index
 PID_QUEUE = 1  # queue-wait timeline: tid = request uid
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """Context manager for the profiler span ``serve.<name>``; ``args``
+    (ints or strings, e.g. the request's ``uid``) become the event's stats.
+    More stats can be attached before it closes with ``set_metadata``."""
+    return TraceAnnotation(f"serve.{name}", **args)
 
 
 class RequestTrace:

@@ -120,6 +120,7 @@ from repro.core.quantize import quantize_base_params
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.obs import Telemetry
+from repro.obs.tracing import span
 from repro.models.lane_state import extract_lane, restore_lane
 from repro.serving.config import EngineConfig
 from repro.serving.lam_store import LamStore, extract_lambda
@@ -181,6 +182,20 @@ def _bucket_len(n: int, max_len: int, floor: int = _MIN_PREFILL_BUCKET) -> int:
     while b < n:
         b *= 2
     return min(b, max_len)
+
+
+def _program(name: str, fn, **jit_kw):
+    """``jax.jit`` of ``fn`` as the device program ``name``: the profiler's
+    ``XLA Modules`` events read ``jit_<name>(…)`` and its ops carry the
+    ``<name>`` scope, whatever the Python closure is called."""
+
+    @functools.wraps(fn)
+    def body(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    body.__name__ = body.__qualname__ = name
+    return jax.jit(body, **jit_kw)
 
 
 class MultiTenantEngine:
@@ -572,24 +587,31 @@ class MultiTenantEngine:
 
         # model-forward jits trace adapted_matmul, which consults the
         # logical-axis rules for the λ-table sharding — keep the rule
-        # context active around every call (the tracing one included)
-        self._prefill = self._with_rules(jax.jit(_prefill))
-        self._decode_jit = jax.jit(_decode, static_argnums=(4,))
+        # context active around every call (the tracing one included).
+        # Program names are stable: trace reductions find the model-step
+        # and KV-pool programs by them.
+        self._prefill = self._with_rules(_program("serve_prefill", _prefill))
+        self._decode_jit = _program("serve_decode", _decode, static_argnums=(4,))
         self._decode = self._with_rules(self._decode_jit)
-        self._restore = jax.jit(_restore)
-        self._extract = jax.jit(_extract)
-        self._reset = jax.jit(_reset)
-        self._prefill_paged = self._with_rules(jax.jit(_prefill_paged))
-        self._prefill_chunk = self._with_rules(jax.jit(_prefill_chunk))
-        self._prefill_chunk_final = self._with_rules(jax.jit(_prefill_chunk_final))
-        self._append_block = jax.jit(_append_block)
-        self._fork_block = jax.jit(_fork_block)
-        self._import_blocks = jax.jit(_import_blocks)
-        self._adopt_lane = jax.jit(_adopt_lane)
+        self._restore = _program("serve_restore_lane", _restore)
+        self._extract = _program("serve_extract_lane", _extract)
+        self._reset = _program("serve_reset_lane", _reset)
+        self._prefill_paged = self._with_rules(
+            _program("serve_prefill_paged", _prefill_paged))
+        self._prefill_chunk = self._with_rules(
+            _program("serve_prefill_chunk", _prefill_chunk))
+        self._prefill_chunk_final = self._with_rules(
+            _program("serve_prefill_chunk_final", _prefill_chunk_final))
+        self._append_block = _program("serve_append_block", _append_block)
+        self._fork_block = _program("serve_fork_block", _fork_block)
+        self._import_blocks = _program("serve_import_blocks", _import_blocks)
+        self._adopt_lane = _program("serve_adopt_lane", _adopt_lane)
         if spec_k:
-            self._draft = self._with_rules(jax.jit(_draft, static_argnums=(4,)))
-            self._verify = self._with_rules(jax.jit(_verify, static_argnums=(5,)))
-            self._commit_advance = jax.jit(_commit_advance)
+            self._draft = self._with_rules(
+                _program("serve_draft", _draft, static_argnums=(4,)))
+            self._verify = self._with_rules(
+                _program("serve_verify", _verify, static_argnums=(5,)))
+            self._commit_advance = _program("serve_commit_advance", _commit_advance)
 
         # engine-level callback metrics: sampled only at snapshot() time,
         # zero hot-path cost.  The jit compile counts hook the same
@@ -1173,7 +1195,7 @@ class MultiTenantEngine:
                 self.allocator.decref(blocks.pop())
                 self.cache = self._append_block(self.cache, lane, slot, 0)
 
-    def _step_speculative(self, decoding, tok, ab, finished, t) -> None:
+    def _step_speculative(self, decoding, tok, ab, finished) -> None:
         """One speculative decode step: draft k tokens per lane with the
         cheap drafter view (one dispatch, throwaway cache), verify every
         lane's (k+1)-token window under the full multi-λ view (one
@@ -1183,59 +1205,52 @@ class MultiTenantEngine:
         is *exact* acceptance — the emitted tokens and logits rows are
         identical to the plain engine's, delivered up to k+1 at a time."""
         tel = self.telemetry
-        on = tel.enabled
         k = self.speculate_k
         seg = jnp.asarray(self.scheduler.batch_composition())
         view = self._params_view()
         dview = self._draft_params_view()
-        t_disp = tel.now() if on else 0.0
-        drafts = np.asarray(
-            self._draft(dview, self.cache, jnp.asarray(tok), seg, ab)
-        )  # host sync fences the draft dispatch
-        if on:
-            tel.on_spec_phase("draft", t_disp, tel.now())
+        with tel.on_spec_phase("draft") as draft:
+            drafts = np.asarray(
+                self._draft(dview, self.cache, jnp.asarray(tok), seg, ab)
+            )  # host sync fences the draft dispatch
         window = np.zeros((self.n_lanes, k + 1), np.int32)
         window[:, 0] = tok[:, 0]
         window[:, 1:] = drafts
         n_valid = np.zeros((self.n_lanes,), np.int32)
         for req in decoding:
             n_valid[req.lane] = self._spec_window_cap(req)
-        t_ver = tel.now() if on else 0.0
-        logits, greedy, cache = self._verify(
-            view, self.cache, jnp.asarray(window), seg,
-            jnp.asarray(n_valid), ab,
-        )
-        logits_np = np.asarray(logits)  # host sync: the verify really ran
-        greedy_np = np.asarray(greedy)
-        t_sync = tel.now() if on else 0.0
-        if on:
-            tel.on_spec_phase("verify", t_ver, t_sync)
-        adv = np.zeros((self.n_lanes,), np.int32)
-        for req in decoding:
-            lane, nv = req.lane, int(n_valid[req.lane])
-            a = 1  # window[0] is the lane's own last token — always accepted
-            while a < nv and window[lane, a] == greedy_np[lane, a - 1]:
-                a += 1
-            adv[lane] = a
-        self.cache = self._commit_advance(cache, jnp.asarray(adv))
-        if self.paged:
-            self._rollback_window_blocks(decoding, adv)
-        self.steps += 1
-        self.spec_steps += 1
-        drafted = k * len(decoding)
-        accepted = int(adv.sum()) - len(decoding)
-        self.drafted_tokens += drafted
-        self.accepted_drafts += accepted
-        tel.on_speculate(drafted, accepted, drafted - accepted)
-        for req in decoding:
-            lane, a = req.lane, int(adv[req.lane])
-            req.slice_steps += a  # quantum accounting in accepted TOKENS
-            for j in range(a):
-                self._emit(req, logits_np[lane, j], finished)
-            if on:
-                tel.on_decode_lane(req, t_disp, t_sync, req.tokens[-1])
-        if on:
-            tel.phase("emit", tel.now() - t_sync)
+        with tel.on_spec_phase("verify") as verify:
+            logits, greedy, cache = self._verify(
+                view, self.cache, jnp.asarray(window), seg,
+                jnp.asarray(n_valid), ab,
+            )
+            logits_np = np.asarray(logits)  # host sync: the verify really ran
+            greedy_np = np.asarray(greedy)
+        with tel.span("emit"):
+            adv = np.zeros((self.n_lanes,), np.int32)
+            for req in decoding:
+                lane, nv = req.lane, int(n_valid[req.lane])
+                a = 1  # window[0] is the lane's own last token — always accepted
+                while a < nv and window[lane, a] == greedy_np[lane, a - 1]:
+                    a += 1
+                adv[lane] = a
+            self.cache = self._commit_advance(cache, jnp.asarray(adv))
+            if self.paged:
+                self._rollback_window_blocks(decoding, adv)
+            self.steps += 1
+            self.spec_steps += 1
+            drafted = k * len(decoding)
+            accepted = int(adv.sum()) - len(decoding)
+            self.drafted_tokens += drafted
+            self.accepted_drafts += accepted
+            tel.on_speculate(drafted, accepted, drafted - accepted)
+            for req in decoding:
+                lane, a = req.lane, int(adv[req.lane])
+                req.slice_steps += a  # quantum accounting in accepted TOKENS
+                for j in range(a):
+                    self._emit(req, logits_np[lane, j], finished)
+                if tel.enabled:
+                    tel.on_decode_lane(req, draft.t0, verify.t1, req.tokens[-1])
 
     @property
     def acceptance_rate(self) -> float:
@@ -1247,10 +1262,12 @@ class MultiTenantEngine:
 
     # -- the serving loop ---------------------------------------------------
 
-    def _admit(self, finished: List[Request]) -> None:
+    def _admit(self, finished: List[Request]) -> int:
+        """Admit what the gate lets through; returns how many."""
         gate = self._make_gate()
         tel = self.telemetry
-        for req in self.scheduler.admit(gate):
+        admitted = self.scheduler.admit(gate)
+        for req in admitted:
             tel.on_admit(req, restored=req.snapshot is not None)
             view = self._params_view()  # after gate: promotion bumps version
             req.slot = self.lam_store.lookup(req.tenant)  # pinned since submit
@@ -1272,52 +1289,54 @@ class MultiTenantEngine:
             padded[:P] = req.prompt
             length = jnp.full((1,), P, jnp.int32)
             t0 = tel.now() if tel.enabled else 0.0
-            if self.paged:
-                payload = self._imports.pop(req.uid, None)
-                if payload is not None:
-                    # disaggregated admission: the prompt's K/V was computed
-                    # on a prefill replica and shipped — splice it in, no
-                    # prompt forward pass (serving/router.py)
-                    row = self._adopt_prefilled(req, payload)
-                    if tel.enabled:
-                        tel.on_prefill(req, t0, tel.now())
-                    self._emit(req, row, finished)
-                    continue
-                self.prefill_buckets.add(Pb)
-                if (
-                    self.prefill_chunk is not None
-                    and self._chunkable
-                    and Pb > self.prefill_chunk
-                    and (
-                        P > 2 * self.prefill_chunk
-                        or self._gate_matches.get(req.uid)
+            payload = self._imports.pop(req.uid, None) if self.paged else None
+            if payload is not None:
+                # disaggregated admission: the prompt's K/V was computed on a
+                # prefill replica and shipped — splice it in, no prompt
+                # forward pass (serving/router.py)
+                row = self._adopt_prefilled(req, payload)
+                if tel.enabled:
+                    tel.on_prefill(req, t0, tel.now())
+                self._emit(req, row, finished)
+                continue
+            self.prefill_buckets.add(Pb)
+            if (
+                self.paged
+                and self.prefill_chunk is not None
+                and self._chunkable
+                and Pb > self.prefill_chunk
+                and (
+                    P > 2 * self.prefill_chunk
+                    or self._gate_matches.get(req.uid)
+                )
+            ):
+                # long prompt: allocate its blocks now, stream its chunks
+                # through the following steps' prefill budget.  Short
+                # prompts (≤ 2 chunks of work) auto-disable — splitting one
+                # dispatch into two buys no TBT bound and pays a second
+                # dispatch — UNLESS a cached prefix lets the chunk path skip
+                # resident blocks' compute entirely (the monolithic path
+                # recomputes them into the trash).
+                self._begin_chunked_prefill(req, padded, seg, length, t0)
+                continue
+            with span("prefill", uid=req.uid, prompt=P):
+                if self.paged:
+                    logits = self._admit_paged(req, view, padded, seg, length)
+                else:
+                    lane_cache = self.model.init_decode_state(
+                        1, self.max_len, self.dtype, per_lane=True
                     )
-                ):
-                    # long prompt: allocate its blocks now, stream its
-                    # chunks through the following steps' prefill budget.
-                    # Short prompts (≤ 2 chunks of work) auto-disable —
-                    # splitting one dispatch into two buys no TBT bound and
-                    # pays a second dispatch — UNLESS a cached prefix lets
-                    # the chunk path skip resident blocks' compute entirely
-                    # (the monolithic path recomputes them into the trash).
-                    self._begin_chunked_prefill(req, padded, seg, length, t0)
-                    continue
-                logits = self._admit_paged(req, view, padded, seg, length)
-            else:
-                self.prefill_buckets.add(Pb)
-                lane_cache = self.model.init_decode_state(
-                    1, self.max_len, self.dtype, per_lane=True
-                )
-                logits, lane_cache = self._prefill(
-                    view, lane_cache, jnp.asarray(padded)[None, :], seg, length
-                )
-                self.cache = self._restore(self.cache, lane_cache, req.lane)
-            # materialize before timing: the host sync is part of the
-            # prefill cost the lane actually paid
-            row = np.asarray(logits[0])
+                    logits, lane_cache = self._prefill(
+                        view, lane_cache, jnp.asarray(padded)[None, :], seg, length
+                    )
+                    self.cache = self._restore(self.cache, lane_cache, req.lane)
+                # materialize before timing: the host sync is part of the
+                # prefill cost the lane actually paid
+                row = np.asarray(logits[0])
             if tel.enabled:
                 tel.on_prefill(req, t0, tel.now())
             self._emit(req, row, finished)
+        return len(admitted)
 
     def _admit_paged(self, req: Request, view, padded, seg, length):
         """Paged admission: adopt the shared-prefix blocks the gate pinned,
@@ -1445,18 +1464,19 @@ class MultiTenantEngine:
                 view = self._params_view()
                 toks = jnp.asarray(st["tokens"][start: start + C])[None, :]
                 t0 = tel.now() if tel.enabled else 0.0
-                if last:
-                    logits, self.cache = self._prefill_chunk_final(
-                        view, self.cache, toks, st["seg"], st["length"],
-                        np.int32(start), st["write_ids"], st["read_ids"],
-                        req.lane, st["table_row"],
-                    )
-                    row = np.asarray(logits[0])  # host sync: chunk really ran
-                else:
-                    self.cache = self._prefill_chunk(
-                        view, self.cache, toks, st["seg"], st["length"],
-                        np.int32(start), st["write_ids"], st["read_ids"],
-                    )
+                with span("prefill_chunk", uid=req.uid, start=start):
+                    if last:
+                        logits, self.cache = self._prefill_chunk_final(
+                            view, self.cache, toks, st["seg"], st["length"],
+                            np.int32(start), st["write_ids"], st["read_ids"],
+                            req.lane, st["table_row"],
+                        )
+                        row = np.asarray(logits[0])  # host sync: chunk ran
+                    else:
+                        self.cache = self._prefill_chunk(
+                            view, self.cache, toks, st["seg"], st["length"],
+                            np.int32(start), st["write_ids"], st["read_ids"],
+                        )
                 st["next"] += 1
                 req.prefill_pos = -1 if last else st["starts"][st["next"]]
                 if tel.enabled:
@@ -1538,41 +1558,28 @@ class MultiTenantEngine:
         finished: List[Request] = []
         self.events = []
         tel = self.telemetry
-        on = tel.enabled
-        t = tel.now() if on else 0.0
         if self.quantum is not None and self.scheduler.queue:
-            # preempt only as many over-quantum lanes as waiters that free
-            # lanes can't already absorb (counted before preemption re-queues
-            # victims), most-overdue first — otherwise every expiry would
-            # churn lanes through extract/restore that admission could have
-            # filled for free
-            need = len(self.scheduler.queue) - len(self.scheduler.free_lanes())
-            if need > 0:
-                over = [r for r in self.scheduler.active() if r.slice_steps >= self.quantum]
-                over.sort(key=lambda r: (-r.slice_steps, r.lane))
-                for req in over[:need]:
-                    self._preempt_quantum(req)
-            if on:
-                now = tel.now()
-                tel.phase("quantum", now - t)
-                t = now
-        self._admit(finished)
-        if on:
-            now = tel.now()
-            tel.phase("admit", now - t)
-            t = now
+            with tel.span("quantum"):
+                # preempt only as many over-quantum lanes as waiters that
+                # free lanes can't already absorb (counted before preemption
+                # re-queues victims), most-overdue first — otherwise every
+                # expiry would churn lanes through extract/restore that
+                # admission could have filled for free
+                need = len(self.scheduler.queue) - len(self.scheduler.free_lanes())
+                if need > 0:
+                    over = [r for r in self.scheduler.active()
+                            if r.slice_steps >= self.quantum]
+                    over.sort(key=lambda r: (-r.slice_steps, r.lane))
+                    for req in over[:need]:
+                        self._preempt_quantum(req)
+        with tel.span("admit") as sp:
+            sp.set_metadata(admitted=self._admit(finished))
         if self._prefilling:
-            self._run_prefill_chunks(finished)
-            if on:
-                now = tel.now()
-                tel.phase("prefill_chunk", now - t)
-                t = now
+            with tel.span("prefill_chunk"):
+                self._run_prefill_chunks(finished)
         if self.paged:
-            self._grow_lanes(self.speculate_k + 1 if self.speculate_k else 1)
-            if on:
-                now = tel.now()
-                tel.phase("grow", now - t)
-                t = now
+            with tel.span("grow"):
+                self._grow_lanes(self.speculate_k + 1 if self.speculate_k else 1)
         active = self.scheduler.active()
         # mid-chunked-prefill lanes occupy a lane but have no token to
         # decode yet — they ride the shared step as masked rows (their
@@ -1607,19 +1614,16 @@ class MultiTenantEngine:
                 ab *= 2
             ab = min(ab, self.max_blocks)
         if self.speculate_k:
-            self._step_speculative(decoding, tok, ab, finished, t)
+            self._step_speculative(decoding, tok, ab, finished)
             return {"finished": finished, "decoding": None}
-        seg = jnp.asarray(self.scheduler.batch_composition())
-        view = self._params_view()
-        t_disp = tel.now() if on else 0.0
-        logits, self.cache = self._decode(view, self.cache, jnp.asarray(tok), seg, ab)
-        if on:
-            now = tel.now()
-            tel.phase("dispatch", now - t_disp)
-            t = now
+        with tel.span("dispatch", lanes=len(decoding), attend_blocks=ab or 0) as sp:
+            seg = jnp.asarray(self.scheduler.batch_composition())
+            view = self._params_view()
+            logits, self.cache = self._decode(
+                view, self.cache, jnp.asarray(tok), seg, ab)
         return {
             "finished": finished, "decoding": decoding, "logits": logits,
-            "t_disp": t_disp, "t": t,
+            "t_disp": sp.t0,
         }
 
     def step_finish(self, pending: Dict[str, Any]) -> List[Request]:
@@ -1631,21 +1635,16 @@ class MultiTenantEngine:
         if decoding is None:
             return finished
         tel = self.telemetry
-        on = tel.enabled
-        logits_np = np.asarray(pending["logits"])  # host sync: decode ran
-        t_sync = 0.0
-        if on:
-            t_sync = tel.now()
-            tel.phase("sync", t_sync - pending["t"])
+        with tel.span("sync") as sp:
+            logits_np = np.asarray(pending["logits"])  # host sync: decode ran
         self.steps += 1
-        t_disp = pending["t_disp"]
-        for req in decoding:
-            req.slice_steps += 1
-            self._emit(req, logits_np[req.lane], finished)
-            if on:
-                tel.on_decode_lane(req, t_disp, t_sync, req.tokens[-1])
-        if on:
-            tel.phase("emit", tel.now() - t_sync)
+        t_disp, t_sync = pending["t_disp"], sp.t1
+        with tel.span("emit"):
+            for req in decoding:
+                req.slice_steps += 1
+                self._emit(req, logits_np[req.lane], finished)
+                if tel.enabled:
+                    tel.on_decode_lane(req, t_disp, t_sync, req.tokens[-1])
         return finished
 
     def step(self) -> List[Request]:

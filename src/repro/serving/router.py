@@ -59,14 +59,12 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.tracing import Tracer
+from repro.obs.tracing import span
 from repro.serving.lam_store import lam_digest
 from repro.serving.replica import (
     EngineReplica, LocalTransport, Transport, payload_nbytes,
 )
 
-#: Tracer process id for router-level spans (engines own pids 0/1).
-PID_ROUTER = 2
 
 #: Virtual ring nodes per replica: smooths the digest-space split so two
 #: replicas get ~half each instead of whatever two raw hash points carve.
@@ -171,9 +169,6 @@ class Router:
         self._awaiting_prefill: List[RoutedRequest] = []
         # -- observability
         self.registry = MetricsRegistry(enabled=telemetry)
-        self.tracer = Tracer() if telemetry else None
-        if self.tracer is not None:
-            self.tracer._process_name(PID_ROUTER, "router")
         reg = self.registry
         self._m_requests = reg.counter(
             "router_requests_total", "requests accepted by the router")
@@ -289,19 +284,14 @@ class Router:
                 best, src = got, sib
         if best is None:
             return 0
-        t0 = self.tracer.now() if self.tracer else 0.0
-        payload = self.transport.ship(best, src, target, "prefix")
-        adopted = eng.import_prefix(tenant, prompt, payload)
+        with span("router.ship_prefix", src=src.replica_id,
+                  dst=target.replica_id) as sp:
+            payload = self.transport.ship(best, src, target, "prefix")
+            adopted = eng.import_prefix(tenant, prompt, payload)
+            sp.set_metadata(blocks=adopted)
         if adopted:
             self._m_imports.inc()
             self._m_xfer.labels(kind="prefix").inc(payload_nbytes(payload))
-            if self.tracer:
-                self.tracer.complete(
-                    "ship_prefix", PID_ROUTER, target.replica_id,
-                    t0, self.tracer.now() - t0,
-                    args={"from": src.name, "to": target.name,
-                          "blocks": adopted},
-                )
         return adopted
 
     # -- submission ----------------------------------------------------------
@@ -375,28 +365,23 @@ class Router:
             if not er.tokens or er.uid in src.engine._prefilling:
                 still.append(routed)
                 continue
-            t0 = self.tracer.now() if self.tracer else 0.0
-            payload = src.engine.export_request_state(er)
-            src.engine.cancel(er)
-            self._by_engine.pop((src.replica_id, er.uid), None)
-            dst, _ = self.place(routed.tenant)
-            shipped = self.transport.ship(payload, src, dst, "prefill")
-            self._m_xfer.labels(kind="prefill").inc(payload_nbytes(shipped))
-            self._ensure_resident(dst, [routed.tenant])
-            routed.phase = "decode"
-            routed.replica = dst
-            routed.engine_req = dst.engine.inject_prefilled(
-                routed.tenant, routed.prompt, routed.max_new_tokens, shipped)
+            with span("router.ship_prefill", uid=er.uid,
+                      src=src.replica_id) as sp:
+                payload = src.engine.export_request_state(er)
+                src.engine.cancel(er)
+                self._by_engine.pop((src.replica_id, er.uid), None)
+                dst, _ = self.place(routed.tenant)
+                sp.set_metadata(dst=dst.replica_id, blocks=payload["n_blocks"])
+                shipped = self.transport.ship(payload, src, dst, "prefill")
+                self._m_xfer.labels(kind="prefill").inc(payload_nbytes(shipped))
+                self._ensure_resident(dst, [routed.tenant])
+                routed.phase = "decode"
+                routed.replica = dst
+                routed.engine_req = dst.engine.inject_prefilled(
+                    routed.tenant, routed.prompt, routed.max_new_tokens, shipped)
             routed.placements += 1
             self._by_engine[(dst.replica_id, routed.engine_req.uid)] = routed
             self._m_place.labels(outcome="handoff").inc()
-            if self.tracer:
-                self.tracer.complete(
-                    "ship_prefill", PID_ROUTER, dst.replica_id,
-                    t0, self.tracer.now() - t0,
-                    args={"from": src.name, "to": dst.name,
-                          "blocks": payload["n_blocks"]},
-                )
         self._awaiting_prefill = still
 
     # -- failure handling ----------------------------------------------------

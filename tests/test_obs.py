@@ -358,3 +358,74 @@ def test_engine_disabled_telemetry_is_inert():
     with pytest.raises(RuntimeError):
         eng.telemetry.write_trace("/tmp/never.json")
     assert len(r.tokens) == 4  # serving itself is unaffected
+
+
+# ---------------------------------------------------------------------------
+# profiler spans and device-program names
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def profiled_engine(tmp_path_factory):
+    """A paged engine with chunked prefill, run once to compile,
+    then again under a ``jax.profiler`` capture; returns the engine, its
+    requests and the ``serve.*`` events of the capture as
+    (name, stats) pairs."""
+    import glob
+
+    import jax
+
+    cfg = get_reduced("smollm-135m").replace(dtype="float32")
+    eng = MultiTenantEngine(cfg, EngineConfig(
+        layout="paged", n_lanes=2, n_slots=3, max_len=64, block_size=8,
+        prefill_chunk=8))
+
+    def serve():
+        reqs = [eng.submit(BASE_TENANT, np.arange(2, 2 + n, dtype=np.int32), g)
+                for n, g in ((6, 4), (20, 3), (9, 5))]
+        eng.run()
+        return reqs
+
+    serve()
+    out = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(out)):
+        reqs = serve()
+    path = glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    spans = [(e.name, {k: v for k, v in e.stats})
+             for plane in pd.planes if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    return eng, reqs, spans
+
+
+def test_engine_spans_on_the_profilers_clock(profiled_engine):
+    _, reqs, spans = profiled_engine
+    names = {n for n, _ in spans}
+    assert {"serve.admit", "serve.prefill", "serve.grow", "serve.dispatch",
+            "serve.sync", "serve.emit"} <= names
+    # every monolithic prefill and every chunk names its request
+    uids = {r.uid for r in reqs}
+    prefills = [a for n, a in spans if n == "serve.prefill"]
+    assert prefills and all(a["uid"] in uids and a["prompt"] > 0 for a in prefills)
+    chunks = [a for n, a in spans if n == "serve.prefill_chunk" and "uid" in a]
+    assert {a["uid"] for a in chunks} == {reqs[1].uid}  # the 20-token prompt
+    assert sorted(a["start"] for a in chunks) == [0, 8, 16]
+    # the decode dispatch says how many lanes decoded and how far they attend
+    dispatch = [a for n, a in spans if n == "serve.dispatch"]
+    assert dispatch
+    assert all(1 <= a["lanes"] <= 2 and a["attend_blocks"] >= 1 for a in dispatch)
+    assert sum(a["admitted"] for n, a in spans if n == "serve.admit") >= len(reqs)
+
+
+def test_every_step_phase_has_a_span_of_its_name(profiled_engine):
+    eng, _, spans = profiled_engine
+    snap = eng.metrics()
+    phases = {s["labels"]["phase"] for s in snap["serve_step_phase_ms"]["series"]}
+    assert {"admit", "prefill_chunk", "grow", "dispatch", "sync", "emit"} <= phases
+    assert {f"serve.{p}" for p in phases} <= {n for n, _ in spans}
+
+
+def test_decode_lowers_as_a_stably_named_program():
+    _, eng = _tiny_engine()
+    assert eng.lower_decode().as_text().startswith("module @jit_serve_decode")
