@@ -257,46 +257,98 @@ def attention(
     return shard(o, "batch", None, None), new_cache
 
 
+def block_view(rows: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Paged-pool rows ``(…, block_size·KV·dh)`` → their ``(…, block_size,
+    KV, dh)`` view: one block's tokens, token-major, each ``(KV, dh)``."""
+    KV, dh = cfg.n_kv_heads, cfg.d_head
+    return rows.reshape(*rows.shape[:-1], rows.shape[-1] // (KV * dh), KV, dh)
+
+
+def block_rows(view):
+    """Inverse of ``block_view``: ``(…, block_size, KV, dh)`` → the pool's
+    rows ``(…, block_size·KV·dh)``."""
+    bs, KV, dh = view.shape[-3:]
+    return view.reshape(*view.shape[:-3], bs * KV * dh)
+
+
+def _write_rows(pool, layer, blk, rows):
+    """Write whole block rows into the stacked pool ``(G, n_blocks,
+    block_size·KV·dh)`` in place: ``rows[t]`` → ``pool[layer, blk[t]]``.  A
+    window that spans the row lowers to one native in-place scatter on the
+    carried pool; a window narrower than the row (one token) would be
+    expanded into a loop with one update per token.  Targets repeat only
+    at trash block 0 (idle lanes, padding), where any of them may win."""
+    at = jnp.stack([jnp.broadcast_to(layer, blk.shape), blk], axis=1).astype(jnp.int32)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(0, 1),
+        scatter_dims_to_operand_dims=(0, 1),
+    )
+    return jax.lax.scatter(
+        pool, at, rows.astype(pool.dtype), dnums,
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
+def _write_tokens(pool, layer, blk, off, vals, cfg: ModelConfig):
+    """Write one token per lane, ``vals[b]`` (KV, dh) at offset ``off[b]``
+    of block ``blk[b]``: read each lane's row, put the token in, write the
+    rows back whole (``_write_rows``).  The lanes' real target blocks must
+    be distinct (each decoding lane owns its write block)."""
+    cur = block_view(pool[layer, blk], cfg)  # (B, bs, KV, dh)
+    here = (jnp.arange(cur.shape[1])[None, :] == off[:, None])[:, :, None, None]
+    rows = jnp.where(here, vals[:, None].astype(pool.dtype), cur)
+    return _write_rows(pool, layer, blk, block_rows(rows))
+
+
 def _paged_prefill(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt, positions):
     """Block-aligned prefill against a paged cache.
 
-    ``cache`` is a prompt-shaped view (``transformer.paged_prefill_view``):
-    ``k``/``v`` are the shared pools and ``block_tbl`` names this pass's
-    *write targets* per block — freshly allocated private blocks, or trash
-    block 0 standing in for already-resident shared prefix blocks and for
-    bucket padding.  ``positions`` are the *absolute* sequence positions of
-    this pass's rows: ``arange(S)`` for a whole-prompt prefill, or
-    ``start + arange(chunk)`` for one chunk of a chunked prefill.  Position
-    ``t`` of lane ``b`` scatters to ``pool[tbl[b, t // bs], t % bs]``
-    instead of a dense ``(max_len,)`` lane region the engine would
-    re-splice.
+    ``cache`` is a prompt-shaped view (``transformer.paged_prefill_view``)
+    as the layer scan hands it over: ``k``/``v`` are the engine's whole
+    stacked pools ``(G, n_blocks, block_size·KV·dh)``, ``layer`` this
+    layer's index into them, and ``block_tbl`` names this pass's *write
+    targets* per block — freshly allocated private blocks, or trash block 0
+    standing in for already-resident shared prefix blocks and for bucket
+    padding.  ``positions`` are the *absolute* sequence positions of this
+    pass's rows: ``arange(S)`` for a whole-prompt prefill, or ``start +
+    arange(chunk)`` for one chunk of a chunked prefill.  Position ``t`` of
+    lane ``b`` is written in place to the ``(KV, dh)`` segment ``t % bs`` of
+    row ``pool[layer, tbl[b, t // bs]]``.  The pass starts on a block
+    boundary (the engine's chunks are block multiples from block-aligned
+    starts), so it writes whole block rows, one native in-place scatter
+    per pool; a bucket that ends mid-block fills the rest of its last block
+    with padding no position reads.
 
     Without ``read_tbl`` in the view, attention is the plain causal pass
     over the (bucketed) prompt — bit-identical to the dense prefill path.
     With it (chunked prefill), the keys are *gathered back from the pool*
-    through ``read_tbl`` (full prompt-bucket width) after the scatter, so a
-    chunk attends to every earlier chunk's K/V — including prefix-cache
-    blocks whose K/V was never recomputed — under the absolute causal mask
-    ``kpos <= positions``.  The scatter-then-gather round-trip returns the
-    chunk's own K/V bit-identically (pool dtype == compute dtype) and the
-    gather width equals the monolithic bucket, so the softmax reduces over
-    identical score vectors and chunked prefill is bit-identical to
-    monolithic prefill, row for row.
+    as ``pool[layer, read_tbl]`` (full prompt-bucket width) after the
+    write, so a chunk attends to every earlier chunk's K/V — including
+    prefix-cache blocks whose K/V was never recomputed — under the absolute
+    causal mask ``kpos <= positions``.  The write-then-gather round-trip
+    returns the chunk's own K/V bit-identically (pool dtype == compute
+    dtype) and the gather width equals the monolithic bucket, so the
+    softmax reduces over identical score vectors and chunked prefill is
+    bit-identical to monolithic prefill, row for row.
     """
     B, S, H, dh = q.shape
-    n_blocks, bs = cache["k"].shape[0], cache["k"].shape[1]
+    KV = cfg.n_kv_heads
+    kp, vp, layer = cache["k"], cache["v"], cache["layer"]
+    bs = kp.shape[-1] // (KV * dh)
     tbl = cache["block_tbl"]
 
     pos = positions.astype(jnp.int32)
-    blk = jnp.take_along_axis(tbl, jnp.broadcast_to(pos // bs, (B, S)), axis=1)
-    flat = (blk * bs + pos[None, :] % bs).reshape(-1)  # (B·S,)
-    kp = cache["k"].reshape(n_blocks * bs, *cache["k"].shape[2:])
-    vp = cache["v"].reshape(n_blocks * bs, *cache["v"].shape[2:])
-    kp = kp.at[flat].set(k.reshape(B * S, *k.shape[2:]).astype(kp.dtype))
-    vp = vp.at[flat].set(v.reshape(B * S, *v.shape[2:]).astype(vp.dtype))
+    nb = -(-S // bs)  # the pass's blocks, written as whole rows
+    blk = jnp.take_along_axis(
+        tbl, jnp.broadcast_to(pos[::bs] // bs, (B, nb)), axis=1
+    ).reshape(-1)
+    pad = ((0, 0), (0, nb * bs - S), (0, 0), (0, 0))  # past the bucket
+    rows = lambda x: block_rows(jnp.pad(x, pad).reshape(B * nb, bs, KV, dh))  # noqa: E731
+    kp = _write_rows(kp, layer, blk, rows(k))
+    vp = _write_rows(vp, layer, blk, rows(v))
     new_cache = {
-        "k": kp.reshape(cache["k"].shape),
-        "v": vp.reshape(cache["v"].shape),
+        "k": kp,
+        "v": vp,
         "block_tbl": tbl,
         "idx": jnp.full_like(cache["idx"], S),  # true length overrides in decoder_prefill
     }
@@ -305,8 +357,8 @@ def _paged_prefill(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt, positio
     if read_tbl is not None:  # chunked prefill: attend through the pool
         new_cache["read_tbl"] = read_tbl
         W = read_tbl.shape[1] * bs
-        kg = kp.reshape(cache["k"].shape)[read_tbl].reshape(B, W, *kp.shape[1:])
-        vg = vp.reshape(cache["v"].shape)[read_tbl].reshape(B, W, *vp.shape[1:])
+        kg = block_view(kp[layer, read_tbl], cfg).reshape(B, W, KV, dh)
+        vg = block_view(vp[layer, read_tbl], cfg).reshape(B, W, KV, dh)
         mask = (jnp.arange(W)[None, :] <= pos[:, None])[None, None, None]
         out = _softmax_attend(
             q, kg.astype(q.dtype), vg.astype(q.dtype), mask, scale, scores_dtype=sdt
@@ -324,11 +376,17 @@ def _paged_decode(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt,
                   attend_blocks: Optional[int] = None):
     """One decode step against a paged KV cache.
 
-    ``cache``: ``k``/``v`` pools (n_blocks, bs, KV, dh), ``block_tbl``
-    (B, max_blocks) int32, ``idx`` (B,) per-lane lengths.  Lane ``b``'s
-    token ``t`` lives at ``pool[block_tbl[b, t // bs], t % bs]``; idle lanes
-    point at trash block 0 (never allocated) so the shared scatter needs no
-    per-lane branching.
+    ``cache``, as the layer scan hands it over: ``k``/``v`` the whole
+    stacked pools ``(G, n_blocks, block_size·KV·dh)`` (one row per block),
+    ``layer`` this layer's index into them, ``block_tbl`` (B, max_blocks)
+    int32, ``idx`` (B,) per-lane lengths.  Lane ``b``'s token ``t`` is the
+    ``(KV, dh)`` segment ``t % bs`` of row ``pool[layer, block_tbl[b, t //
+    bs]]``; idle lanes point at trash block 0 (never allocated) so the
+    shared write needs no per-lane branching.  The write reads each lane's
+    current row, puts the token in and scatters the rows back into the
+    carried (donated) pools in place; the XLA attend gathers ``pool[layer,
+    tbl]``: no layer's pool is sliced, relaid out or copied.  (The Pallas
+    kernel, ``attn_impl="pallas"``, takes this layer's pool as a slice.)
 
     ``attend_blocks`` (static, from the engine's active-lane high-water
     mark) truncates the *attend* to the table's first columns so the
@@ -339,8 +397,9 @@ def _paged_decode(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt,
     contributes exactly zero at any width.
     """
     B = q.shape[0]
-    H, dh = cfg.n_heads, cfg.d_head
-    n_blocks, bs = cache["k"].shape[0], cache["k"].shape[1]
+    H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    kp, vp, layer = cache["k"], cache["v"], cache["layer"]
+    bs = kp.shape[-1] // (KV * dh)
     tbl, idx = cache["block_tbl"], cache["idx"]
     max_blocks = tbl.shape[1]
     nm = _decode_shard_names(cfg)
@@ -348,17 +407,12 @@ def _paged_decode(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt,
     k = shard(k, "batch", None, *nm)
     v = shard(v, "batch", None, *nm)
 
-    # -- write: scatter this step's k/v into each lane's current block ------
+    # -- write: this step's k/v into each lane's current block, in place ----
     blk = jnp.take_along_axis(
         tbl, jnp.clip(idx // bs, 0, max_blocks - 1)[:, None], axis=1
     )[:, 0]
-    flat = blk * bs + idx % bs  # (B,) — distinct across active lanes
-    kp = cache["k"].reshape(n_blocks * bs, *cache["k"].shape[2:])
-    vp = cache["v"].reshape(n_blocks * bs, *cache["v"].shape[2:])
-    kp = kp.at[flat].set(k[:, 0].astype(kp.dtype))
-    vp = vp.at[flat].set(v[:, 0].astype(vp.dtype))
-    kp = shard(kp.reshape(cache["k"].shape), None, None, *nm)
-    vp = shard(vp.reshape(cache["v"].shape), None, None, *nm)
+    kp = _write_tokens(kp, layer, blk, idx % bs, k[:, 0], cfg)
+    vp = _write_tokens(vp, layer, blk, idx % bs, v[:, 0], cfg)
     new_cache = {"k": kp, "v": vp, "block_tbl": tbl, "idx": idx + 1}
 
     # -- attend through the block table -------------------------------------
@@ -371,13 +425,18 @@ def _paged_decode(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt,
     if cfg.attn_impl == "pallas":
         from repro.kernels import ops as kernel_ops
 
-        out = kernel_ops.paged_decode_attention(q, kp, vp, tbl, lengths)
+        out = kernel_ops.paged_decode_attention(
+            q, block_view(kp[layer], cfg), block_view(vp[layer], cfg), tbl, lengths
+        )
     else:
-        kg = kp[tbl].reshape(B, a_blocks * bs, *kp.shape[2:]).astype(q.dtype)
-        vg = vp[tbl].reshape(B, a_blocks * bs, *vp.shape[2:]).astype(q.dtype)
+        kg = block_view(kp[layer, tbl], cfg).reshape(B, a_blocks * bs, KV, dh)
+        vg = block_view(vp[layer, tbl], cfg).reshape(B, a_blocks * bs, KV, dh)
         kpos = jnp.arange(a_blocks * bs)
         mask = (kpos[None, :] < lengths[:, None])[:, None, None, None, :]
-        out = _softmax_attend(q, kg, vg, mask, scale, decode=True, scores_dtype=sdt)
+        out = _softmax_attend(
+            q, kg.astype(q.dtype), vg.astype(q.dtype), mask, scale, decode=True,
+            scores_dtype=sdt,
+        )
     o = adapted_matmul(out.reshape(B, 1, H * dh), p["wo"], (adp or {}).get("wo"))
     return shard(o, "batch", None, None), new_cache
 
@@ -428,7 +487,9 @@ def _dense_verify(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt, n_valid)
 def _paged_verify(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt, n_valid,
                   attend_blocks: Optional[int] = None):
     """Speculative verify against the paged pool: W window rows per lane
-    scattered through its block table at ``idx .. idx+W-1``.
+    written in place through its block table at ``idx .. idx+W-1`` (the
+    stacked pools, ``layer`` and the per-token write as in
+    ``_paged_decode``, one window row after another).
 
     Rows ``s >= n_valid[b]`` have their block forced to trash block 0 — a
     lane whose window exceeds its owned blocks (or an idle lane) scribbles
@@ -441,8 +502,9 @@ def _paged_verify(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt, n_valid,
     accepted advances and decref/trash-repoints past-the-end blocks.
     """
     B, W = q.shape[:2]
-    H, dh = cfg.n_heads, cfg.d_head
-    n_blocks, bs = cache["k"].shape[0], cache["k"].shape[1]
+    H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    kp, vp, layer = cache["k"], cache["v"], cache["layer"]
+    bs = kp.shape[-1] // (KV * dh)
     tbl, idx = cache["block_tbl"], cache["idx"]
     max_blocks = tbl.shape[1]
     nm = _decode_shard_names(cfg)
@@ -454,26 +516,25 @@ def _paged_verify(p, q, k, v, cache, cfg: ModelConfig, adp, scale, sdt, n_valid,
     valid = jnp.arange(W)[None, :] < n_valid[:, None]
     blk = jnp.take_along_axis(tbl, jnp.clip(pos // bs, 0, max_blocks - 1), axis=1)
     blk = jnp.where(valid, blk, 0)  # invalid rows → trash block
-    flat = (blk * bs + pos % bs).reshape(-1)
-    kp = cache["k"].reshape(n_blocks * bs, *cache["k"].shape[2:])
-    vp = cache["v"].reshape(n_blocks * bs, *cache["v"].shape[2:])
-    kp = kp.at[flat].set(k.reshape(B * W, *k.shape[2:]).astype(kp.dtype))
-    vp = vp.at[flat].set(v.reshape(B * W, *v.shape[2:]).astype(vp.dtype))
-    kp = shard(kp.reshape(cache["k"].shape), None, None, *nm)
-    vp = shard(vp.reshape(cache["v"].shape), None, None, *nm)
+    for s in range(W):  # in window order: rows s and s+1 may share a block
+        kp = _write_tokens(kp, layer, blk[:, s], pos[:, s] % bs, k[:, s], cfg)
+        vp = _write_tokens(vp, layer, blk[:, s], pos[:, s] % bs, v[:, s], cfg)
     new_cache = {"k": kp, "v": vp, "block_tbl": tbl, "idx": idx}
 
     a_blocks = max_blocks
     if attend_blocks is not None and attend_blocks < max_blocks:
         a_blocks = max(attend_blocks, 1)
         tbl = tbl[:, :a_blocks]
-    kg = kp[tbl].reshape(B, a_blocks * bs, *kp.shape[2:]).astype(q.dtype)
-    vg = vp[tbl].reshape(B, a_blocks * bs, *vp.shape[2:]).astype(q.dtype)
+    kg = block_view(kp[layer, tbl], cfg).reshape(B, a_blocks * bs, KV, dh)
+    vg = block_view(vp[layer, tbl], cfg).reshape(B, a_blocks * bs, KV, dh)
     kpos = jnp.arange(a_blocks * bs)
     mask = (
         kpos[None, None, :] <= jnp.minimum(pos, a_blocks * bs - 1)[:, :, None]
     )[:, None, None]  # (B,1,1,W,a_blocks*bs)
-    out = _softmax_attend(q, kg, vg, mask, scale, decode=True, scores_dtype=sdt)
+    out = _softmax_attend(
+        q, kg.astype(q.dtype), vg.astype(q.dtype), mask, scale, decode=True,
+        scores_dtype=sdt,
+    )
     o = adapted_matmul(out.reshape(B, W, H * dh), p["wo"], (adp or {}).get("wo"))
     return shard(o, "batch", None, None), new_cache
 
@@ -502,8 +563,8 @@ def kv_lane_axes(lead_ndim: int):
 
 
 def paged_kv_lane_axes():
-    """Lane-axes tree for the paged KV cache: the k/v block pools are
-    global (lanes address them through their block-table rows), so only
-    ``block_tbl`` ``(G, batch, max_blocks)`` and ``idx`` ``(G, batch)``
-    carry a lane dimension."""
+    """Lane-axes tree for the paged KV cache: the k/v block pools
+    ``(G, n_blocks, block_size·KV·dh)`` are global (lanes address them
+    through their block-table rows), so only ``block_tbl`` ``(G, batch,
+    max_blocks)`` and ``idx`` ``(G, batch)`` carry a lane dimension."""
     return {"k": NO_LANE, "v": NO_LANE, "block_tbl": 1, "idx": 1}

@@ -338,16 +338,34 @@ def _run_groups(
     length=None, attend_blocks=None, n_valid=None,
 ):
     groups = params["groups"]
+    G = jax.tree_util.tree_leaves(groups)[0].shape[0]
+    # A paged attention cache (a block table is present) is written in place:
+    # its stacked K/V pools ride the carry, and each layer writes into and
+    # gathers from them at its own index (xs), so no layer's pool is sliced,
+    # relaid out or stacked back.  Dense and recurrent caches go through xs.
+    pools = None
+    if cache is not None and "block_tbl" in cache.get("attn", {}):
+        att = dict(cache["attn"])
+        pools = (att.pop("k"), att.pop("v"))
+        cache = {**cache, "attn": att}
 
     def body(carry, xs):
-        x, aux = carry
-        p, cache_sl = xs
+        x, aux, pools = carry
+        p, cache_sl, layer = xs
+        if pools is not None:
+            cache_sl = {**cache_sl, "attn": {
+                **cache_sl["attn"], "k": pools[0], "v": pools[1], "layer": layer,
+            }}
         x, new_c, a = _group_body(
             cfg, p, x, cache_sl, positions, img, decode, train=train and cfg.remat,
             seg_ids=seg_ids, length=length, attend_blocks=attend_blocks,
             n_valid=n_valid,
         )
-        return (x, aux + a), new_c
+        if pools is not None:
+            att = dict(new_c["attn"])
+            pools = (att.pop("k"), att.pop("v"))
+            new_c = {**new_c, "attn": att}
+        return (x, aux + a, pools), new_c
 
     f = body
     if train and cfg.remat:
@@ -355,18 +373,26 @@ def _run_groups(
             body, policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False
         )
 
+    carry = (x, jnp.zeros((), jnp.float32), pools)
+    layers = jnp.arange(G, dtype=jnp.int32)
     if cfg.scan_layers:
-        (x, aux), new_cache = jax.lax.scan(f, (x, jnp.zeros((), jnp.float32)), (groups, cache))
+        carry, new_cache = jax.lax.scan(f, carry, (groups, cache, layers))
     else:
-        G = jax.tree_util.tree_leaves(groups)[0].shape[0]
-        aux = jnp.zeros((), jnp.float32)
         ncs = []
         for i in range(G):
-            (x, aux), nc = f((x, aux), (_tslice(groups, i), _tslice(cache, i) if cache is not None else None))
+            carry, nc = f(carry, (
+                _tslice(groups, i), _tslice(cache, i) if cache is not None else None,
+                layers[i],
+            ))
             ncs.append(nc)
         new_cache = (
             jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ncs) if ncs[0] is not None else None
         )
+    x, aux, pools = carry
+    if pools is not None:
+        new_cache = {**new_cache, "attn": {
+            **new_cache["attn"], "k": pools[0], "v": pools[1],
+        }}
     return x, aux, new_cache
 
 
@@ -430,10 +456,16 @@ def init_decode_state(
     (``models/lane_state.py``).
 
     ``paged=True`` (implies per-lane) swaps the dense ``(batch, max_len)``
-    KV region for a global block pool ``(n_blocks, block_size, KV, dh)``
-    per layer plus per-lane block tables ``(batch, max_len/block_size)``
-    int32 — block 0 is the reserved trash block (see serving/paging.py).
+    KV region for global block pools stored as ``(G, n_blocks,
+    block_size·KV·dh)``: one contiguous row per block and layer, a block's
+    tokens one after another (``attention.block_view`` gives a row's
+    ``(block_size, KV, dh)`` view).  Rows keep the device layout natural:
+    tiling a ``(…, KV, dh)`` minor pair would pad it.  Per-lane block
+    tables ``(G, batch, max_len/block_size)`` int32 name each lane's
+    blocks — block 0 is the reserved trash block (see serving/paging.py).
     HBM then scales with actual resident tokens, not ``batch × max_len``.
+    The layer scan carries the stacked pools and writes them in place
+    (``_run_groups``), so a program that donates the cache holds one pool.
     Hybrid pages its attention layers while the Mamba layers keep dense
     per-lane recurrent state in the same cache; a pure-ssm family has no
     attention layers to page and rejects ``paged=True``.
@@ -463,8 +495,8 @@ def init_decode_state(
             n_blocks = 1 + batch * max_blocks  # worst case + trash block
         cache["layers"] = {
             "attn": {
-                "k": jnp.zeros((G, n_blocks, block_size, KV, dh), dtype),
-                "v": jnp.zeros((G, n_blocks, block_size, KV, dh), dtype),
+                "k": jnp.zeros((G, n_blocks, block_size * KV * dh), dtype),
+                "v": jnp.zeros((G, n_blocks, block_size * KV * dh), dtype),
                 "block_tbl": jnp.zeros((G, batch, max_blocks), jnp.int32),
                 "idx": jnp.zeros((G, batch), jnp.int32),
             },
@@ -519,13 +551,15 @@ def decode_state_lane_axes(cfg: ModelConfig, paged: bool = False) -> Dict[str, P
 def paged_prefill_view(cfg: ModelConfig, cache, write_ids, read_ids=None):
     """1-lane paged-cache view for block-aligned admission prefill.
 
-    Aliases the full engine cache's pools; the single block-table row is
-    ``write_ids`` (ceil(bucket/block_size),) — this pass's *write targets*
-    per block, with trash block 0 standing in for already-resident shared
-    prefix blocks and bucket padding.  Recurrent layers (hybrid's Mamba)
-    get a fresh 1-lane state — prefill materializes the prompt's recurrent
-    state into it.  ``decoder_prefill`` on this view scatters the prompt's
-    K/V straight into the pool (attention.py's ``_paged_prefill``);
+    Holds the engine cache's own stacked pools, not a copy: the layer scan
+    carries them and ``decoder_prefill`` on this view writes the prompt's
+    K/V into them in place (attention.py's ``_paged_prefill``), so a
+    prefill program that donates the cache updates the one pool.  The
+    single block-table row is ``write_ids`` (ceil(bucket/block_size),) —
+    this pass's *write targets* per block, with trash block 0 standing in
+    for already-resident shared prefix blocks and bucket padding.
+    Recurrent layers (hybrid's Mamba) get a fresh 1-lane state — prefill
+    materializes the prompt's recurrent state into it.
     ``commit_paged_prefill`` folds the result back.
 
     ``read_ids`` (ceil(bucket/block_size),) switches the view to chunked

@@ -33,7 +33,11 @@ Glues the pieces together:
   dense Mamba ``{conv, h}`` rows in the same ``step()``), and ssm xlstm
   (mLSTM/sLSTM states, no KV at all) share one decode loop.
 * ``paged=True`` swaps the dense ``(lanes, max_len)`` KV region for a
-  global block pool + per-lane block tables (``serving/paging.py``).
+  global block pool + per-lane block tables (``serving/paging.py``).  The
+  decode and prefill programs write the pool in place
+  (``models/transformer._run_groups``) and they and the KV pool programs
+  donate the cache, so one pool is live (a speculative verify holds a
+  second until its commit).
   Admission allocates only the *prompt's* ``ceil(P/block_size)`` blocks and
   prefills them **block-aligned** — the prompt's K/V scatters straight into
   pool blocks (``models/attention._paged_prefill``), no dense lane-1
@@ -119,6 +123,7 @@ from repro.core import adapter_api
 from repro.core.quantize import quantize_base_params
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
+from repro.models.attention import block_rows, block_view
 from repro.obs import Telemetry
 from repro.obs.tracing import span
 from repro.models.lane_state import extract_lane, restore_lane
@@ -511,11 +516,12 @@ class MultiTenantEngine:
 
         def _import_blocks(cache, ids, kblk, vblk):
             """Adopt shipped K/V pool blocks (cross-replica prefix import /
-            disaggregated prefill): scatter whole blocks into the slots
-            ``ids``.  The id vector is padded to ``max_blocks`` width so
-            every import shares one compilation; padding entries point at
-            trash block 0 and carry zero rows — clobbering the trash block
-            is the established write-redirect convention."""
+            disaggregated prefill): scatter whole block rows ``(G,
+            max_blocks, block_size·KV·dh)`` into the slots ``ids``.  The id
+            vector is padded to ``max_blocks`` width so every import shares
+            one compilation; padding entries point at trash block 0 and
+            carry zero rows — clobbering the trash block is the established
+            write-redirect convention."""
             a = cache["layers"]["attn"]
             attn = {
                 **a,
@@ -590,28 +596,39 @@ class MultiTenantEngine:
         # context active around every call (the tracing one included).
         # Program names are stable: trace reductions find the model-step
         # and KV-pool programs by them.
+        # Every program that returns the whole engine cache donates it: each
+        # caller rebinds ``self.cache`` to the result, the model step writes
+        # the paged pools in place (``transformer._run_groups``) and the KV
+        # pool programs pass them through, so one pool is live.
+        # ``serve_draft`` and ``serve_verify`` must not donate (the draft's
+        # rollback is the untouched input; the verified cache is committed
+        # by ``serve_commit_advance``).
         self._prefill = self._with_rules(_program("serve_prefill", _prefill))
-        self._decode_jit = _program("serve_decode", _decode, static_argnums=(4,))
+        self._decode_jit = _program(
+            "serve_decode", _decode, static_argnums=(4,), donate_argnums=(1,))
         self._decode = self._with_rules(self._decode_jit)
-        self._restore = _program("serve_restore_lane", _restore)
+        self._restore = _program("serve_restore_lane", _restore, donate_argnums=(0,))
         self._extract = _program("serve_extract_lane", _extract)
-        self._reset = _program("serve_reset_lane", _reset)
-        self._prefill_paged = self._with_rules(
-            _program("serve_prefill_paged", _prefill_paged))
-        self._prefill_chunk = self._with_rules(
-            _program("serve_prefill_chunk", _prefill_chunk))
-        self._prefill_chunk_final = self._with_rules(
-            _program("serve_prefill_chunk_final", _prefill_chunk_final))
-        self._append_block = _program("serve_append_block", _append_block)
-        self._fork_block = _program("serve_fork_block", _fork_block)
-        self._import_blocks = _program("serve_import_blocks", _import_blocks)
-        self._adopt_lane = _program("serve_adopt_lane", _adopt_lane)
+        self._reset = _program("serve_reset_lane", _reset, donate_argnums=(0,))
+        self._prefill_paged = self._with_rules(_program(
+            "serve_prefill_paged", _prefill_paged, donate_argnums=(1,)))
+        self._prefill_chunk = self._with_rules(_program(
+            "serve_prefill_chunk", _prefill_chunk, donate_argnums=(1,)))
+        self._prefill_chunk_final = self._with_rules(_program(
+            "serve_prefill_chunk_final", _prefill_chunk_final, donate_argnums=(1,)))
+        self._append_block = _program(
+            "serve_append_block", _append_block, donate_argnums=(0,))
+        self._fork_block = _program("serve_fork_block", _fork_block, donate_argnums=(0,))
+        self._import_blocks = _program(
+            "serve_import_blocks", _import_blocks, donate_argnums=(0,))
+        self._adopt_lane = _program("serve_adopt_lane", _adopt_lane, donate_argnums=(0,))
         if spec_k:
             self._draft = self._with_rules(
                 _program("serve_draft", _draft, static_argnums=(4,)))
             self._verify = self._with_rules(
                 _program("serve_verify", _verify, static_argnums=(5,)))
-            self._commit_advance = _program("serve_commit_advance", _commit_advance)
+            self._commit_advance = _program(
+                "serve_commit_advance", _commit_advance, donate_argnums=(0,))
 
         # engine-level callback metrics: sampled only at snapshot() time,
         # zero hot-path cost.  The jit compile counts hook the same
@@ -829,14 +846,13 @@ class MultiTenantEngine:
         )
         if not blocks:
             return None
-        a = self.cache["layers"]["attn"]
-        ids = jnp.asarray(np.asarray(blocks, np.int32))
+        k, v = self._export_blocks(blocks)
         return {
             "block_size": self.block_size,
             "n_blocks": len(blocks),
             "tokens": prompt[: len(blocks) * self.block_size].copy(),
-            "k": np.asarray(a["k"][:, ids]),
-            "v": np.asarray(a["v"][:, ids]),
+            "k": k,
+            "v": v,
         }
 
     def import_prefix(self, tenant: str, prompt, payload) -> int:
@@ -870,18 +886,28 @@ class MultiTenantEngine:
             self.allocator.decref(b)  # the cache now holds the only ref
         return take
 
+    def _export_blocks(self, blocks):
+        """Host copies of pool blocks ``blocks`` on every layer, in the
+        shipped per-block shape ``(G, n, block_size, KV, dh)`` (the pool
+        stores each block as one row)."""
+        a = self.cache["layers"]["attn"]
+        ids = jnp.asarray(np.asarray(blocks, np.int32))
+        return tuple(
+            block_view(np.asarray(a[pool][:, ids]), self.cfg) for pool in ("k", "v")
+        )
+
     def _write_imported_blocks(self, new_ids, payload, *, skip: int) -> None:
         """Scatter payload blocks ``skip:`` into the freshly allocated pool
-        slots ``new_ids`` via the fixed-width ``_import_blocks`` jit."""
+        slots ``new_ids`` via the fixed-width ``_import_blocks`` jit (each
+        shipped block flattened on the host to the pool's row)."""
         take = len(new_ids)
         ids = np.zeros((self.max_blocks,), np.int32)
         ids[:take] = new_ids
-        G = payload["k"].shape[0]
-        kb = np.zeros((G, self.max_blocks) + payload["k"].shape[2:],
-                      payload["k"].dtype)
+        k, v = (block_rows(payload[pool][:, skip: skip + take]) for pool in ("k", "v"))
+        kb = np.zeros((k.shape[0], self.max_blocks, k.shape[2]), k.dtype)
         vb = np.zeros_like(kb)
-        kb[:, :take] = payload["k"][:, skip: skip + take]
-        vb[:, :take] = payload["v"][:, skip: skip + take]
+        kb[:, :take] = k
+        vb[:, :take] = v
         self.cache = self._import_blocks(
             self.cache, jnp.asarray(ids), jnp.asarray(kb), jnp.asarray(vb)
         )
@@ -899,14 +925,13 @@ class MultiTenantEngine:
             raise ValueError("request has no committed prefill to export")
         nb = self.allocator.blocks_for(req.prompt.size)
         blocks = self._lane_blocks[req.lane][:nb]
-        a = self.cache["layers"]["attn"]
-        ids = jnp.asarray(np.asarray(blocks, np.int32))
+        k, v = self._export_blocks(blocks)
         return {
             "block_size": self.block_size,
             "prompt": req.prompt.copy(),
             "n_blocks": len(blocks),
-            "k": np.asarray(a["k"][:, ids]),
-            "v": np.asarray(a["v"][:, ids]),
+            "k": k,
+            "v": v,
             "logits": req.logits[0] if req.logits else None,
             "token": req.tokens[0],
         }

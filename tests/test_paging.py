@@ -274,7 +274,11 @@ def _run_engine(cfg, paged, specs, rng_seed=3, **kw):
     rng = np.random.default_rng(rng_seed)
     reqs = {}
     for t, P, G in specs:
-        prompt = rng.integers(2, cfg.vocab_size, size=P).astype(np.int32)
+        # P: a prompt length, or the prompt itself (shared prefixes)
+        prompt = (
+            P if isinstance(P, np.ndarray)
+            else rng.integers(2, cfg.vocab_size, size=P).astype(np.int32)
+        )
         r = eng.submit(t, prompt, G)
         reqs[r.uid] = (t, prompt, G)
     done = eng.run()
@@ -282,6 +286,18 @@ def _run_engine(cfg, paged, specs, rng_seed=3, **kw):
 
 
 SPECS = [(BASE_TENANT, 6, 4), ("t1", 9, 5), ("t2", 7, 3), ("t1", 13, 4)]
+_PRE = np.random.default_rng(17).integers(2, 256, size=16).astype(np.int32)
+# Every way the paged pool is written: monolithic prefill (P ≤ 16), chunked
+# prefill (P > 2 chunks), a prefix-cache hit (t1's 2-block prefix again,
+# chunked past the cached blocks), decode appends across block boundaries,
+# and lane resets at retirement, two lanes at a time.
+MIXED_SPECS = [
+    ("t1", np.concatenate([_PRE, np.arange(3, 7, dtype=np.int32)]), 6),
+    (BASE_TENANT, 35, 6),
+    ("t2", 9, 4),
+    ("t1", np.concatenate([_PRE, np.arange(9, 14, dtype=np.int32)]), 5),
+    (BASE_TENANT, 13, 5),
+]
 
 
 def test_engine_paged_matches_dense_tokens_and_logits():
@@ -297,6 +313,128 @@ def test_engine_paged_matches_dense_tokens_and_logits():
         np.testing.assert_array_equal(np.stack(rd.logits), np.stack(rp.logits))
     # pool fully drained back to the free list
     assert paged_eng.allocator.n_free == paged_eng.allocator.capacity
+
+    # the pool written in place by every path at once (donated steps, carried
+    # pools): chunked and monolithic prefill, a prefix hit, appends, resets
+    _, _, _, dense_done = _run_engine(cfg, paged=False, specs=MIXED_SPECS)
+    eng, _, _, paged_done = _run_engine(
+        cfg, paged=True, specs=MIXED_SPECS, share_prefix=True, prefill_chunk=16
+    )
+    assert dense_done.keys() == paged_done.keys()
+    for uid in dense_done:
+        assert dense_done[uid].tokens == paged_done[uid].tokens, f"uid={uid}"
+    snap = eng.metrics()
+    assert snap["serve_prefill_chunk_ms"]["series"][0]["count"] >= 3
+    assert eng.prefix_cache.hits == 2
+    assert eng.prefill_compilations >= 2  # monolithic buckets ran too
+    eng.release_prefix_cache()
+    assert eng.allocator.n_free == eng.allocator.capacity
+
+
+def _record_caches(eng, names, arg=1):
+    """Wrap the engine programs ``names`` so each call's ``cache`` argument
+    (positional ``arg``) is kept: (program, cache) in call order."""
+    seen = []
+    for name in names:
+        def call(*args, _f=getattr(eng, name), _name=name):
+            seen.append((_name, args[arg]))
+            return _f(*args)
+        setattr(eng, name, call)
+    return seen
+
+
+def _deleted(cache):
+    """The cache was donated: every leaf's buffer is gone."""
+    return all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(cache))
+
+
+def test_engine_model_steps_donate_the_cache():
+    """Decode and paged prefill (whole-prompt and chunked) donate the cache
+    they are given, so one pool is live: the tree each of them was handed
+    is deleted once the step is done.  A speculative step's draft and
+    verify do not donate (draft rollback is the untouched input)."""
+    cfg = get_reduced("smollm-135m")
+    eng = MultiTenantEngine(
+        cfg,
+        EngineConfig(
+            layout="paged", n_lanes=2, n_slots=2, max_len=48, block_size=8,
+            prefill_chunk=8,
+        ),
+    )
+    donating = ("_decode", "_prefill_paged", "_prefill_chunk", "_prefill_chunk_final")
+    seen = _record_caches(eng, donating)
+    eng.submit(BASE_TENANT, np.arange(2, 22, dtype=np.int32), 6)  # chunked
+    eng.submit(BASE_TENANT, np.arange(2, 7, dtype=np.int32), 6)  # whole
+    while eng.scheduler.has_work:
+        n = len(seen)
+        eng.step()
+        assert all(_deleted(c) for _, c in seen[n:])
+        assert not _deleted(eng.cache)
+    assert {name for name, _ in seen} == set(donating)
+
+    spec = MultiTenantEngine(
+        cfg,
+        EngineConfig(
+            layout="paged", n_lanes=2, n_slots=2, max_len=48, block_size=8,
+            speculate_k=2,
+        ),
+    )
+    seen = _record_caches(spec, ("_draft", "_verify"))
+    spec.submit(BASE_TENANT, np.arange(2, 7, dtype=np.int32), 8)
+    spec.run()
+    assert {name for name, _ in seen} == {"_draft", "_verify"}
+    assert not any(_deleted(c) for _, c in seen)
+
+
+def test_engine_kv_pool_programs_donate_the_cache():
+    """The KV pool programs donate the engine cache too, so no whole-pool
+    copy is ever made and one pool is live: after each call the tree it
+    was handed is deleted — block-table growth, lane resets, copy-on-write
+    forks, the disaggregated-prefill import and adoption, the dense
+    layout's lane restore, and the speculative offset commit."""
+    cfg = get_reduced("smollm-135m")
+    pool_programs = (
+        "_append_block", "_reset", "_fork_block", "_import_blocks", "_adopt_lane",
+    )
+
+    def paged(**kw):
+        return MultiTenantEngine(
+            cfg,
+            EngineConfig(
+                layout="paged", n_lanes=2, n_slots=2, max_len=48, block_size=8, **kw,
+            ),
+        )
+
+    prefill, decode = paged(), paged()
+    seen = _record_caches(decode, pool_programs, arg=0)
+    first = prefill.submit(BASE_TENANT, np.arange(2, 14, dtype=np.int32), 6)
+    prefill.step()  # prefill only: the first token is committed
+    payload = prefill.export_request_state(first)
+    moved = decode.inject_prefilled(BASE_TENANT, first.prompt, 6, payload)
+    own = decode.submit(BASE_TENANT, np.arange(2, 14, dtype=np.int32), 6)
+    decode.step()  # both admitted; ``own``'s tail block (8..11) is private
+    tail = decode._lane_blocks[own.lane][-1]
+    decode.allocator.incref(tail)  # a second owner: the next write forks it
+    done = decode.run()
+    assert moved.uid in done and own.uid in done
+    assert {name for name, _ in seen} == set(pool_programs)
+    assert all(_deleted(c) for _, c in seen)
+    decode.allocator.decref(tail)
+
+    dense = MultiTenantEngine(
+        cfg, EngineConfig(layout="oracle_dense", n_lanes=2, n_slots=2, max_len=48)
+    )
+    seen = _record_caches(dense, ("_restore",), arg=0)
+    dense.submit(BASE_TENANT, np.arange(2, 9, dtype=np.int32), 3)
+    dense.run()
+    assert [name for name, _ in seen] == ["_restore"]
+    assert _deleted(seen[0][1])
+
+    spec = paged(speculate_k=2)
+    seen = _record_caches(spec, ("_commit_advance",), arg=0)
+    spec.submit(BASE_TENANT, np.arange(2, 7, dtype=np.int32), 8)
+    spec.run()
+    assert seen and all(_deleted(c) for _, c in seen)
 
 
 def test_engine_paged_matches_merged_weight_reference():

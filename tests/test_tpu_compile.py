@@ -5,14 +5,21 @@ rules, so a block shape the chip refuses passes there.  These tests lower
 and compile the BGMV kernels through the ``ops`` wrappers — and so through
 their block-size rule — for one chip of a described ``v5e:2x2`` topology,
 at smollm-135m's adapted projection shapes (d_model 576; wq 576→576, wv
-576→192 with 3 KV heads of 64; QR rank cap 128).  Nothing runs on a device.
+576→192 with 3 KV heads of 64; QR rank cap 128).  It also compiles the
+serving engine's own decode, chunked-prefill and KV pool programs at
+smollm-135m widths and checks that they keep the paged KV pool in place.
+Nothing runs on a device.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels import ops
+from repro.models.model_zoo import build_model
+from repro.serving import EngineConfig, MultiTenantEngine
 
 K = 576  # smollm-135m d_model
 RANK = 128  # smollm-135m QR rank cap
@@ -35,24 +42,29 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def tpu_compile(one_chip, monkeypatch):
-    """Compile ``fn`` for one described v5e chip with the kernels' TPU
-    branch taken (this process's backend is the CPU, so ``ops`` would pick
-    interpret mode).  The persistent cache is off: a TPU executable written
-    here could not be read back without a chip."""
+def tpu_branch(monkeypatch):
+    """The kernels' TPU branch taken (this process's backend is the CPU, so
+    ``ops`` would pick interpret mode), and the persistent cache off: a TPU
+    executable written here could not be read back without a chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture
+def tpu_compile(one_chip, tpu_branch):
+    """Compile ``fn`` for one described v5e chip."""
 
     def compile_(fn, *specs):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
         return jax.jit(fn).lower(*args).compile()
 
-    yield compile_
-    jax.config.update("jax_enable_compilation_cache", prev)
+    return compile_
 
 
 SHAPES = [
@@ -108,3 +120,99 @@ def test_qrlora_matmul_compiles_for_v5e(tpu_compile, M, N):
 def test_lane_block_rule(dim, cap, want):
     """Largest 128-multiple divisor up to the cap, else the whole dim."""
     assert ops._lane_block(dim, cap) == want
+
+
+# ---------------------------------------------------------------------------
+# the serving engine's model-step programs write the paged pool in place
+# ---------------------------------------------------------------------------
+
+LANES = 64  # the benchmark cell's decode lanes
+# Two pool sizes: whatever a program holds beyond the pool it aliases must
+# not grow with the pool.
+POOL_BLOCKS = (4_096, 8_192)
+CHUNK = 256  # the cell's prefill chunk; a 1,024-token prompt's bucket
+PROGRAMS = {  # case → (engine program, decode attend bucket)
+    "serve_decode": ("_decode_jit", 8),
+    "serve_decode_attend128": ("_decode_jit", 128),  # 2,048 positions a lane
+    "serve_prefill_chunk": ("_prefill_chunk", None),
+    "serve_append_block": ("_append_block", None),
+    "serve_reset_lane": ("_reset", None),
+}
+
+
+@pytest.fixture(scope="module")
+def smollm_engine():
+    """A paged engine at smollm-135m widths with a tiny pool: its jits are
+    what the chip runs; the test widens the pool in the shapes it lowers
+    with.  Zero weights: only shapes matter to the compiler."""
+    cfg = get_config("smollm-135m")
+    params = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype), build_model(cfg).dryrun_params()
+    )
+    return MultiTenantEngine(
+        cfg,
+        EngineConfig(
+            layout="paged", n_lanes=LANES, n_slots=9, max_len=2048,
+            block_size=16, n_blocks=8, prefill_chunk=CHUNK,
+        ),
+        params=params,
+    )
+
+
+def _program_args(eng, case, n_blocks, one_chip):
+    """The engine program of ``case`` and its argument shapes, with the
+    pool widened to ``n_blocks``; the cache is the argument it donates."""
+    spec = lambda t, shape=None: jax.ShapeDtypeStruct(  # noqa: E731
+        t.shape if shape is None else shape, t.dtype, sharding=one_chip
+    )
+    cache = jax.tree_util.tree_map(spec, eng.cache)
+    attn = cache["layers"]["attn"]
+    for pool in ("k", "v"):
+        t = attn[pool]  # (G, n_blocks, …)
+        attn[pool] = spec(t, (t.shape[0], n_blocks, *t.shape[2:]))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    name, attend = PROGRAMS[case]
+    fn = getattr(eng, name)
+    if name == "_append_block":
+        return fn, cache, (cache, i32(), i32(), i32())
+    if name == "_reset":
+        return fn, cache, (cache, i32())
+    view = jax.tree_util.tree_map(spec, eng._params_view())
+    if name == "_decode_jit":
+        return fn, cache, (view, cache, i32(LANES, 1), i32(LANES), attend)
+    bs = eng.block_size
+    bucket = 4 * CHUNK
+    return fn, cache, (
+        view, cache, i32(1, CHUNK), i32(1), i32(1), i32(),
+        i32(-(-(bucket + CHUNK) // bs)), i32(bucket // bs),
+    )
+
+
+def _nbytes(tree):
+    return sum(
+        int(np.prod(t.shape)) * t.dtype.itemsize for t in jax.tree_util.tree_leaves(tree)
+    )
+
+
+@pytest.mark.parametrize("case", list(PROGRAMS))
+def test_serve_program_writes_kv_pool_in_place_for_v5e(
+    smollm_engine, one_chip, tpu_branch, case
+):
+    """The engine's programs keep one donated pool: compiled for a
+    described v5e, each aliases both pools to its output, and neither its
+    temporaries nor what it returns beside the cache grow with the pool —
+    no per-layer slice, relayout copy or stacked copy-back of the pool, in
+    the model step (the layer scan carries the pools) or in the KV pool
+    programs (they pass the pools through)."""
+    beside = []
+    for n_blocks in POOL_BLOCKS:
+        fn, cache, args = _program_args(smollm_engine, case, n_blocks, one_chip)
+        memory = fn.lower(*args).compile().memory_analysis()
+        pools = _nbytes([cache["layers"]["attn"][p] for p in ("k", "v")])
+        assert memory.alias_size_in_bytes >= pools, n_blocks
+        beside.append((
+            memory.temp_size_in_bytes, memory.output_size_in_bytes - _nbytes(cache),
+        ))
+    assert beside[0] == beside[1], beside
