@@ -7,7 +7,8 @@ region or paged block table), the Mamba ``{conv, h}`` selective-SSM state,
 the mLSTM ``{conv, C, n, m}`` matrix memory, the sLSTM ``{c, n, h, m}``
 scalar memory — and a composite (jamba-style hybrid) cache nests several of
 them per layer group.  The engine must not care: admission, retirement, and
-preemption all reduce to four operations on a *pytree of lanes*:
+preemption all reduce to four operations on a *pytree of lanes* (and a
+decode step over a few of the lanes to ``gather_lanes`` / ``scatter_lanes``):
 
 * ``init``         — build an ``n_lanes``-wide state
   (``transformer.init_decode_state(..., per_lane=True)``).
@@ -71,6 +72,36 @@ def restore_lane(state: Pytree, axes: Pytree, lane, snapshot: Pytree) -> Pytree:
         return jax.lax.dynamic_update_slice_in_dim(t, s.astype(t.dtype), lane, axis=ax)
 
     return jax.tree_util.tree_map(re, state, axes, snapshot)
+
+
+def gather_lanes(state: Pytree, axes: Pytree, rows) -> Pytree:
+    """The lanes ``rows`` (int32 (R,)) as an R-lane state, in that order:
+    every per-lane leaf gathered along its lane axis, a row out of range
+    filled with zeros (for the paged cache: trash block 0 at offset 0).
+    ``NO_LANE`` leaves pass through whole.  ``scatter_lanes`` writes the
+    R-lane result back."""
+
+    def ga(t, ax):
+        if ax == NO_LANE:
+            return t
+        return jnp.take(t, rows, axis=ax, mode="fill", fill_value=0)
+
+    return jax.tree_util.tree_map(ga, state, axes)
+
+
+def scatter_lanes(state: Pytree, axes: Pytree, rows, sub: Pytree) -> Pytree:
+    """Write the R-lane ``sub`` (a ``gather_lanes(state, axes, rows)`` after
+    some step) back into lanes ``rows`` of ``state``; rows out of range are
+    dropped, every other lane is untouched, and ``NO_LANE`` leaves are
+    taken from ``sub``."""
+
+    def sc(t, ax, s):
+        if ax == NO_LANE:
+            return s
+        at = (slice(None),) * ax + (rows,)
+        return t.at[at].set(s.astype(t.dtype), mode="drop")
+
+    return jax.tree_util.tree_map(sc, state, axes, sub)
 
 
 def reset_lane(state: Pytree, axes: Pytree, lane, init_snapshot: Pytree) -> Pytree:

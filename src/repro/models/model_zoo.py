@@ -161,6 +161,12 @@ class Model:
             self.cfg, cache, filled, lane, table_row, length
         )
 
+    def paged_decode_view(self, cache, rows):
+        return tfm_lib.paged_decode_view(self.cfg, cache, rows)
+
+    def commit_paged_decode(self, cache, decoded, rows):
+        return tfm_lib.commit_paged_decode(self.cfg, cache, decoded, rows)
+
     def prefill(self, params, cache, tokens=None, embeds=None, image_embeds=None,
                 seg_ids=None, length=None, start=None):
         return tfm_lib.decoder_prefill(

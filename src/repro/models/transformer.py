@@ -622,6 +622,30 @@ def commit_paged_prefill(cfg: ModelConfig, cache, filled, lane, table_row, lengt
     return {"pos": pos, "layers": layers}
 
 
+def paged_decode_view(cfg: ModelConfig, cache, rows):
+    """The lanes ``rows`` (int32 (R,)) of the paged engine cache as an
+    R-lane cache for one decode step: each per-lane leaf (block-table
+    rows, offsets, positions, recurrent states) gathered along its lane
+    axis, a row out of range (padding) reading trash block 0 at offset 0,
+    and the engine's own stacked pools held whole, so the step writes them
+    in place as it does the full batch.  ``commit_paged_decode`` folds
+    the stepped view back."""
+    from repro.models import lane_state
+
+    return lane_state.gather_lanes(cache, decode_state_lane_axes(cfg, paged=True), rows)
+
+
+def commit_paged_decode(cfg: ModelConfig, cache, decoded, rows):
+    """Adopt a decode step over ``paged_decode_view(cache, rows)``: the
+    stepped pools, and each row's per-lane leaves written back to its lane.
+    Padding rows are dropped; lanes outside ``rows`` are untouched."""
+    from repro.models import lane_state
+
+    return lane_state.scatter_lanes(
+        cache, decode_state_lane_axes(cfg, paged=True), rows, decoded
+    )
+
+
 def decoder_prefill(
     params, cfg: ModelConfig, cache, tokens=None, embeds=None, image_embeds=None,
     seg_ids=None, length=None, start=None,

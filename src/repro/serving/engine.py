@@ -46,7 +46,12 @@ Glues the pieces together:
   exhausted, unreferenced prefix-cache blocks are scavenged first, then the
   *youngest* lane is preempted back to the front of the queue (its blocks
   freed, its output re-derived deterministically on re-admission), so the
-  oldest lane can always finish — decode never deadlocks.
+  oldest lane can always finish — decode never deadlocks.  A paged decode
+  step runs only the lanes that decode, padded to a power-of-two lane
+  bucket of at least 8 rows (``_MIN_DECODE_ROWS``): their per-lane leaves
+  are gathered into a compact view and scattered back
+  (``Model.paged_decode_view`` / ``commit_paged_decode``), so the attend's
+  gather, the step and the logits copy scale with the live lanes.
 * ``share_prefix=True`` adds **copy-on-write prefix sharing**: a hash-chain
   cache maps (tenant-family λ digest, prefill bucket, full-block token
   prefix) → pool block, so requests repeating a prompt prefix *reuse* the
@@ -136,6 +141,11 @@ from repro.sharding.rules import axis_rules, param_sharding_rules
 Pytree = Any
 
 _MIN_PREFILL_BUCKET = 8
+#: Fewest rows a paged decode step runs: its lane bucket is the smallest
+#: power of two that holds the decoding lanes, floored here (one f32
+#: sublane tile; a request driven alone compiles the variant a step with
+#: up to 8 live lanes uses) and capped at the lane count.
+_MIN_DECODE_ROWS = 8
 
 #: Families whose prompt forward pass is position-local outside attention
 #: (token-table embedding, no recurrent mixer), so prefill can run in
@@ -399,6 +409,7 @@ class MultiTenantEngine:
             self.cache = jax.device_put(self.cache, device)
         self.steps = 0
         self.decoded_tokens = 0
+        self.decode_rows = 0  # rows the decode steps ran, padding included
         self.prefill_buckets: set = set()  # padded lengths actually compiled
 
         model = self.model
@@ -418,13 +429,23 @@ class MultiTenantEngine:
         def _prefill(view, cache, tokens, seg, length):
             return model.prefill(view, cache, tokens=tokens, seg_ids=seg, length=length)
 
-        def _decode(view, cache, tok, seg, attend_blocks):
+        def _decode(view, cache, tok, seg, rows, attend_blocks):
             """One decode step.  ``attend_blocks`` (static, paged layouts)
             bounds the fused attend to the active lanes' block high-water
-            mark — HBM traffic tracks the longest live lane, not max_len."""
-            return model.decode_step(
-                view, cache, token=tok, seg_ids=seg, attend_blocks=attend_blocks
+            mark — HBM traffic tracks the longest live lane, not max_len.
+            ``rows`` (int32 (R,), paged layouts) steps only those lanes,
+            padded out of range: ``tok``, ``seg`` and the logits are R rows
+            in that order, the pools are written in place and every other
+            lane is left as it was.  ``None`` steps every lane in order."""
+            if rows is None:
+                return model.decode_step(
+                    view, cache, token=tok, seg_ids=seg, attend_blocks=attend_blocks
+                )
+            logits, stepped = model.decode_step(
+                view, model.paged_decode_view(cache, rows), token=tok,
+                seg_ids=seg, attend_blocks=attend_blocks,
             )
+            return logits, model.commit_paged_decode(cache, stepped, rows)
 
         def _restore(big, small, lane):
             """Splice a 1-lane tree (admission prefill or preemption
@@ -605,7 +626,7 @@ class MultiTenantEngine:
         # by ``serve_commit_advance``).
         self._prefill = self._with_rules(_program("serve_prefill", _prefill))
         self._decode_jit = _program(
-            "serve_decode", _decode, static_argnums=(4,), donate_argnums=(1,))
+            "serve_decode", _decode, static_argnums=(5,), donate_argnums=(1,))
         self._decode = self._with_rules(self._decode_jit)
         self._restore = _program("serve_restore_lane", _restore, donate_argnums=(0,))
         self._extract = _program("serve_extract_lane", _extract)
@@ -647,6 +668,10 @@ class MultiTenantEngine:
                      lambda: self.decoded_tokens, kind="counter",
                      help="tokens decoded, incl. preemption re-derivation "
                           "(serve_tokens_total is the delivered subset)")
+        reg.callback("serve_decode_rows_total",
+                     lambda: self.decode_rows, kind="counter",
+                     help="rows the decode steps ran, padding included "
+                          "(lanes / rows is the live share)")
         reg.callback("serve_prefill_buckets",
                      lambda: len(self.prefill_buckets),
                      help="distinct padded prompt lengths prefilled "
@@ -1607,7 +1632,7 @@ class MultiTenantEngine:
                 self._grow_lanes(self.speculate_k + 1 if self.speculate_k else 1)
         active = self.scheduler.active()
         # mid-chunked-prefill lanes occupy a lane but have no token to
-        # decode yet — they ride the shared step as masked rows (their
+        # decode yet — a full-batch step carries them as masked rows (their
         # writes hit the trash block, their logits are discarded)
         decoding = (
             [r for r in active if r.uid not in self._prefilling]
@@ -1616,9 +1641,6 @@ class MultiTenantEngine:
         )
         if not decoding:
             return {"finished": finished, "decoding": None}
-        tok = np.zeros((self.n_lanes, 1), np.int32)
-        for req in decoding:
-            tok[req.lane, 0] = req.tokens[-1]
         ab = None
         if self.paged:
             # bound the fused attend to the decoding lanes' block high-water
@@ -1639,16 +1661,38 @@ class MultiTenantEngine:
                 ab *= 2
             ab = min(ab, self.max_blocks)
         if self.speculate_k:
+            tok = np.zeros((self.n_lanes, 1), np.int32)
+            for req in decoding:
+                tok[req.lane, 0] = req.tokens[-1]
             self._step_speculative(decoding, tok, ab, finished)
             return {"finished": finished, "decoding": None}
-        with tel.span("dispatch", lanes=len(decoding), attend_blocks=ab or 0) as sp:
-            seg = jnp.asarray(self.scheduler.batch_composition())
+        # a paged step runs only the decoding lanes, in lane order, padded
+        # to a power-of-two lane bucket with out-of-range rows: idle and
+        # mid-chunked-prefill lanes are left out, not carried as masked rows
+        n_rows = self.n_lanes
+        if self.paged:
+            n_rows = _bucket_len(len(decoding), self.n_lanes, _MIN_DECODE_ROWS)
+        seg = self.scheduler.batch_composition()
+        if n_rows < self.n_lanes:
+            rows = np.full((n_rows,), self.n_lanes, np.int32)
+            rows[: len(decoding)] = [r.lane for r in decoding]
+            seg = np.append(seg, 0)[rows]  # padding rows: slot 0
+            at = list(range(len(decoding)))
+        else:
+            rows, at = None, [r.lane for r in decoding]
+        tok = np.zeros((n_rows, 1), np.int32)
+        for i, req in zip(at, decoding):
+            tok[i, 0] = req.tokens[-1]
+        self.decode_rows += n_rows
+        with tel.span("dispatch", lanes=len(decoding), rows=n_rows,
+                      attend_blocks=ab or 0) as sp:
             view = self._params_view()
             logits, self.cache = self._decode(
-                view, self.cache, jnp.asarray(tok), seg, ab)
+                view, self.cache, jnp.asarray(tok), jnp.asarray(seg),
+                None if rows is None else jnp.asarray(rows), ab)
         return {
-            "finished": finished, "decoding": decoding, "logits": logits,
-            "t_disp": sp.t0,
+            "finished": finished, "decoding": decoding, "at": at,
+            "logits": logits, "t_disp": sp.t0,
         }
 
     def step_finish(self, pending: Dict[str, Any]) -> List[Request]:
@@ -1665,9 +1709,9 @@ class MultiTenantEngine:
         self.steps += 1
         t_disp, t_sync = pending["t_disp"], sp.t1
         with tel.span("emit"):
-            for req in decoding:
+            for i, req in zip(pending["at"], decoding):
                 req.slice_steps += 1
-                self._emit(req, logits_np[req.lane], finished)
+                self._emit(req, logits_np[i], finished)
                 if tel.enabled:
                     tel.on_decode_lane(req, t_disp, t_sync, req.tokens[-1])
         return finished
@@ -1699,15 +1743,19 @@ class MultiTenantEngine:
             self.step()
             yield from self.events
 
-    def lower_decode(self):
-        """Lower the plain decode step for the current lanes at the widest
-        attend bucket — ``.compile().as_text()`` shows what the device runs
-        (e.g. whether the adapter matmuls are the BGMV kernel)."""
-        tok = jnp.zeros((self.n_lanes, 1), jnp.int32)
-        seg = jnp.asarray(self.scheduler.batch_composition())
+    def lower_decode(self, lane_bucket: Optional[int] = None):
+        """Lower the plain decode step at the widest attend bucket over
+        ``lane_bucket`` rows (default: every lane; fewer takes a paged
+        layout) — ``.compile().as_text()`` shows what the device runs (e.g.
+        whether the adapter matmuls are the BGMV kernel)."""
+        n = self.n_lanes if lane_bucket is None else lane_bucket
+        rows = None if n == self.n_lanes else jnp.arange(n, dtype=jnp.int32)
+        tok = jnp.zeros((n, 1), jnp.int32)
+        seg = jnp.asarray(self.scheduler.batch_composition()[:n])
         ab = self.max_blocks if self.paged else None
         with self._rules_ctx():
-            return self._decode_jit.lower(self._params_view(), self.cache, tok, seg, ab)
+            return self._decode_jit.lower(
+                self._params_view(), self.cache, tok, seg, rows, ab)
 
     # -- accounting ---------------------------------------------------------
 

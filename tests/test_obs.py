@@ -415,6 +415,7 @@ def test_engine_spans_on_the_profilers_clock(profiled_engine):
     dispatch = [a for n, a in spans if n == "serve.dispatch"]
     assert dispatch
     assert all(1 <= a["lanes"] <= 2 and a["attend_blocks"] >= 1 for a in dispatch)
+    assert all(a["rows"] == 2 for a in dispatch)  # two lanes: the full batch
     assert sum(a["admitted"] for n, a in spans if n == "serve.admit") >= len(reqs)
 
 
@@ -429,3 +430,47 @@ def test_every_step_phase_has_a_span_of_its_name(profiled_engine):
 def test_decode_lowers_as_a_stably_named_program():
     _, eng = _tiny_engine()
     assert eng.lower_decode().as_text().startswith("module @jit_serve_decode")
+
+
+def test_compact_decode_compiles_once_per_attend_bucket(monkeypatch):
+    """16 lanes with at most 8 live: every paged decode step runs the 8-row
+    lane bucket, so the decode compiles one variant per attend bucket, as
+    it does over all lanes; each dispatch span's ``rows`` and the
+    ``serve_decode_rows_total`` counter read 8 rows a step."""
+    from repro.obs import telemetry as telemetry_mod
+
+    dispatched = []
+    real_span = telemetry_mod.span
+
+    def span(name, **args):
+        if name == "dispatch":
+            dispatched.append(args)
+        return real_span(name, **args)
+
+    monkeypatch.setattr(telemetry_mod, "span", span)
+    cfg = get_reduced("smollm-135m").replace(dtype="float32")
+    eng = MultiTenantEngine(cfg, EngineConfig(
+        layout="paged", n_lanes=16, n_slots=2, max_len=64, block_size=8))
+
+    def rows_total():
+        return eng.metrics()["serve_decode_rows_total"]["series"][0]["value"]
+
+    eng.submit(BASE_TENANT, np.arange(2, 7, dtype=np.int32), 3)
+    eng.step()  # admits and prefills the request, then decodes it
+    assert dispatched == [{"lanes": 1, "rows": 8, "attend_blocks": 1}]
+    assert rows_total() == 8
+    # requests of 2, 4 and 8 blocks alone (attend buckets 2, 4, 8), then
+    # eight at once
+    for n, g in ((9, 6), (20, 10), (40, 20)):
+        eng.submit(BASE_TENANT, np.arange(2, 2 + n, dtype=np.int32), g)
+        eng.run()
+    for n, g in ((3, 4), (12, 9), (30, 3), (6, 2), (9, 6), (5, 5), (7, 3), (4, 8)):
+        eng.submit(BASE_TENANT, np.arange(2, 2 + n, dtype=np.int32), g)
+    eng.run()
+    assert all(1 <= a["lanes"] <= 8 and a["rows"] == 8 for a in dispatched)
+    assert rows_total() == 8 * len(dispatched) == 8 * eng.steps
+    buckets = {a["attend_blocks"] for a in dispatched}
+    assert buckets == {1, 2, 4, 8}
+    compiles = eng.metrics()["serve_jit_compiles_decode"]["series"][0]["value"]
+    assert compiles == len(buckets)
+    assert eng.lower_decode(8).as_text().startswith("module @jit_serve_decode")

@@ -977,3 +977,116 @@ def test_chunked_prefill_skips_cached_prefix_blocks():
     for rm, rc in zip(mono, chunked):
         assert rm.tokens == rc.tokens
         np.testing.assert_array_equal(np.stack(rm.logits), np.stack(rc.logits))
+
+
+# ---------------------------------------------------------------------------
+# compact decode: a paged step runs only its decoding lanes
+# ---------------------------------------------------------------------------
+
+_LIVE_PRE = np.random.default_rng(23).integers(2, 256, size=16).astype(np.int32)
+
+
+def _live_phases(vocab):
+    """Submissions and the steps run after each: 1, then 3, then 9 lanes
+    decode at once on 16 lanes (lane buckets 8, 8 and 16).  A prompt that
+    files a 2-block prefix and is prefilled in chunks, block-boundary
+    appends, a 35-token prompt chunked while 3 lanes decode beside it, a
+    prefix hit, retirements, and six short prompts on reused lanes."""
+    rng = np.random.default_rng(29)
+    p = lambda n: rng.integers(2, vocab, size=n).astype(np.int32)  # noqa: E731
+    return [
+        ([("t1", np.concatenate([_LIVE_PRE, p(3)]), 14)], 5),
+        ([(BASE_TENANT, p(35), 6), ("t2", p(9), 3), (BASE_TENANT, p(6), 6),
+          ("t1", np.concatenate([_LIVE_PRE, p(5)]), 5)], 5),
+        ([(t, p(n), g) for t, n, g in (
+            ("t2", 5, 4), (BASE_TENANT, 12, 5), ("t1", 7, 3), ("t2", 13, 6),
+            (BASE_TENANT, 6, 4), ("t1", 10, 5))], 0),
+    ]
+
+
+def _run_live_phases(cfg, layout, watch=None, **kw):
+    eng = MultiTenantEngine(
+        cfg,
+        EngineConfig(
+            layout=layout, n_lanes=16, n_slots=4, max_len=64, block_size=8,
+            collect_logits=True, **kw,
+        ),
+    )
+    lams = {BASE_TENANT: base_lambda(eng.params)}
+    for i in (1, 2):
+        lams[f"t{i}"] = random_lambda(jax.random.PRNGKey(i), eng.params, scale=0.3)
+        eng.add_tenant(f"t{i}", lams[f"t{i}"])
+    if watch is not None:
+        eng._decode = watch(eng, eng._decode)
+    reqs = []
+    for subs, steps in _live_phases(cfg.vocab_size):
+        reqs += [(eng.submit(t, prompt, g), t, prompt) for t, prompt, g in subs]
+        for _ in range(steps):
+            eng.step()
+    eng.run()
+    return eng, reqs, lams
+
+
+def _lanes_of(cache, lanes):
+    """Positions, block-table rows and offsets of ``lanes``, on the host."""
+    a = cache["layers"]["attn"]
+    return (np.asarray(cache["pos"])[lanes], np.asarray(a["block_tbl"])[:, lanes],
+            np.asarray(a["idx"])[:, lanes])
+
+
+def test_engine_compact_decode_matches_dense_and_leaves_other_lanes(monkeypatch):
+    """A paged step over 1, 3 and 9 of 16 lanes runs a lane bucket of 8, 8
+    and 16 rows.  Every request's tokens equal the dense per-lane engine's;
+    its logits equal, bit for bit, those of the same paged engine stepping
+    all 16 lanes, and the merged-weight reference's at 1e-4.  A compact
+    step leaves every lane outside its rows — free, or mid-way through a
+    chunked prefill — bitwise as it was."""
+    cfg = get_reduced("smollm-135m").replace(dtype="float32")
+    seen = []  # (decoding lanes, rows run, mid-chunk lanes left out)
+
+    def watch(eng, decode):
+        def step(view, cache, tok, seg, rows, ab):
+            lanes = [r.lane for r in eng.scheduler.active()
+                     if r.uid not in eng._prefilling]
+            if rows is None:
+                seen.append((len(lanes), tok.shape[0], 0))
+                return decode(view, cache, tok, seg, rows, ab)
+            out = [i for i in range(eng.n_lanes) if i not in lanes]
+            chunking = {r.lane for r in eng.scheduler.active()} - set(lanes)
+            before = _lanes_of(cache, out)  # read before the cache is donated
+            logits, new = decode(view, cache, tok, seg, rows, ab)
+            for b, c in zip(before, _lanes_of(new, out)):
+                np.testing.assert_array_equal(b, c)
+            assert np.asarray(rows)[: len(lanes)].tolist() == lanes
+            assert (np.asarray(rows)[len(lanes):] == eng.n_lanes).all()
+            seen.append((len(lanes), tok.shape[0], len(chunking)))
+            return logits, new
+        return step
+
+    eng, reqs, lams = _run_live_phases(
+        cfg, "paged", watch, share_prefix=True, prefill_chunk=8
+    )
+    assert {(1, 8), (3, 8), (9, 16)} <= {(n, rows) for n, rows, _ in seen}
+    assert any(rows < 16 and chunking for _, rows, chunking in seen)
+    assert eng.prefix_cache.hits == 2
+    assert eng.metrics()["serve_decode_rows_total"]["series"][0]["value"] == sum(
+        rows for _, rows, _ in seen
+    )
+    eng.release_prefix_cache()
+    assert eng.allocator.n_free == eng.allocator.capacity
+
+    _, dense, _ = _run_live_phases(cfg, "oracle_dense")
+    from repro.serving import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "_MIN_DECODE_ROWS", 16)  # every step full
+    _, full, _ = _run_live_phases(cfg, "paged", share_prefix=True, prefill_chunk=8)
+    for (r, t, prompt), (d, _, _), (f, _, _) in zip(reqs, dense, full):
+        assert r.tokens == d.tokens == f.tokens, f"uid={r.uid}"
+        np.testing.assert_array_equal(np.stack(r.logits), np.stack(f.logits))
+        ref_toks, ref_logits = reference_decode(
+            cfg, eng.params, lams[t], prompt, len(r.tokens), 64
+        )
+        assert r.tokens == ref_toks, f"uid={r.uid}"
+        np.testing.assert_allclose(
+            np.stack(r.logits), ref_logits, atol=1e-4, rtol=1e-4
+        )

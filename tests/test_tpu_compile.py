@@ -44,7 +44,8 @@ def one_chip(topo):
 @pytest.fixture
 def tpu_branch(monkeypatch):
     """The kernels' TPU branch taken (this process's backend is the CPU, so
-    ``ops`` would pick interpret mode), and the persistent cache off: a TPU
+    ``ops`` would pick interpret mode, and the model step the XLA ``take``
+    in place of the BGMV kernel), and the persistent cache off: a TPU
     executable written here could not be read back without a chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -52,6 +53,7 @@ def tpu_branch(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     yield
     jax.config.update("jax_enable_compilation_cache", prev)
 
@@ -131,12 +133,15 @@ LANES = 64  # the benchmark cell's decode lanes
 # not grow with the pool.
 POOL_BLOCKS = (4_096, 8_192)
 CHUNK = 256  # the cell's prefill chunk; a 1,024-token prompt's bucket
-PROGRAMS = {  # case → (engine program, decode attend bucket)
-    "serve_decode": ("_decode_jit", 8),
-    "serve_decode_attend128": ("_decode_jit", 128),  # 2,048 positions a lane
-    "serve_prefill_chunk": ("_prefill_chunk", None),
-    "serve_append_block": ("_append_block", None),
-    "serve_reset_lane": ("_reset", None),
+PROGRAMS = {  # case → (engine program, decode attend bucket, decode rows)
+    "serve_decode": ("_decode_jit", 8, LANES),
+    "serve_decode_attend128": ("_decode_jit", 128, LANES),  # 2,048 positions a lane
+    # the lane bucket of a step with at most 8 of the 64 lanes decoding
+    "serve_decode_rows8": ("_decode_jit", 8, 8),
+    "serve_decode_rows8_attend128": ("_decode_jit", 128, 8),
+    "serve_prefill_chunk": ("_prefill_chunk", None, None),
+    "serve_append_block": ("_append_block", None, None),
+    "serve_reset_lane": ("_reset", None, None),
 }
 
 
@@ -173,7 +178,7 @@ def _program_args(eng, case, n_blocks, one_chip):
     i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.int32, sharding=one_chip
     )
-    name, attend = PROGRAMS[case]
+    name, attend, rows = PROGRAMS[case]
     fn = getattr(eng, name)
     if name == "_append_block":
         return fn, cache, (cache, i32(), i32(), i32())
@@ -181,7 +186,8 @@ def _program_args(eng, case, n_blocks, one_chip):
         return fn, cache, (cache, i32())
     view = jax.tree_util.tree_map(spec, eng._params_view())
     if name == "_decode_jit":
-        return fn, cache, (view, cache, i32(LANES, 1), i32(LANES), attend)
+        lanes = None if rows == LANES else i32(rows)
+        return fn, cache, (view, cache, i32(rows, 1), i32(rows), lanes, attend)
     bs = eng.block_size
     bucket = 4 * CHUNK
     return fn, cache, (
@@ -196,23 +202,62 @@ def _nbytes(tree):
     )
 
 
+@pytest.fixture(scope="module")
+def compiled_program(smollm_engine, one_chip):
+    """A case's program compiled for the described v5e at a pool size, with
+    the cache's shapes; each compiled once."""
+    seen = {}
+
+    def compiled(case, n_blocks):
+        if (case, n_blocks) not in seen:
+            fn, cache, args = _program_args(smollm_engine, case, n_blocks, one_chip)
+            seen[case, n_blocks] = fn.lower(*args).compile(), cache
+        return seen[case, n_blocks]
+
+    return compiled
+
+
 @pytest.mark.parametrize("case", list(PROGRAMS))
 def test_serve_program_writes_kv_pool_in_place_for_v5e(
-    smollm_engine, one_chip, tpu_branch, case
+    compiled_program, tpu_branch, case
 ):
     """The engine's programs keep one donated pool: compiled for a
     described v5e, each aliases both pools to its output, and neither its
     temporaries nor what it returns beside the cache grow with the pool —
     no per-layer slice, relayout copy or stacked copy-back of the pool, in
-    the model step (the layer scan carries the pools) or in the KV pool
+    the model step (the layer scan carries the pools; a step over a few
+    lanes gathers their table rows, not the pool) or in the KV pool
     programs (they pass the pools through)."""
     beside = []
     for n_blocks in POOL_BLOCKS:
-        fn, cache, args = _program_args(smollm_engine, case, n_blocks, one_chip)
-        memory = fn.lower(*args).compile().memory_analysis()
+        compiled, cache = compiled_program(case, n_blocks)
+        memory = compiled.memory_analysis()
         pools = _nbytes([cache["layers"]["attn"][p] for p in ("k", "v")])
         assert memory.alias_size_in_bytes >= pools, n_blocks
+        if PROGRAMS[case][0] == "_decode_jit":  # the adapters stay on the kernel
+            assert "qrlora_bgmv" in compiled.as_text()
         beside.append((
             memory.temp_size_in_bytes, memory.output_size_in_bytes - _nbytes(cache),
         ))
     assert beside[0] == beside[1], beside
+
+
+def test_serve_decode_temporaries_scale_with_its_rows_for_v5e(
+    compiled_program, tpu_branch
+):
+    """Widening the attend from 8 to 128 blocks adds at most a quarter as
+    many temporaries to a decode step over 8 rows as to one over all 64
+    lanes: the attend's gather and its relayout are built for the rows the
+    step runs.  (Every decode step also holds a fixed 62.9 MB temporary,
+    a relaid-out copy of the embedding table, whose space its other
+    temporaries may reuse: so the growth is compared, not the totals.)"""
+    def growth(lo, hi):
+        def temp(case):
+            compiled, _ = compiled_program(case, POOL_BLOCKS[0])
+            return compiled.memory_analysis().temp_size_in_bytes
+
+        return temp(hi) - temp(lo)
+
+    few = growth("serve_decode_rows8", "serve_decode_rows8_attend128")
+    every = growth("serve_decode", "serve_decode_attend128")
+    assert 4 * few <= every, (few, every)
